@@ -26,11 +26,13 @@ same constants as ``bench_coop_vs_indep``) — must stay > 1 and not
 regress; ``balance/<key>`` = mean/max per-PE edge load (1.0 = perfectly
 balanced).
 
-Device mesh: the worker re-execs itself with
-``XLA_FLAGS=--xla_force_host_platform_device_count=P`` so the parent
-benchmark process keeps its single device.
+Device mesh: a CPU run (``JAX_PLATFORMS=cpu``, set explicitly) re-execs
+the worker with ``XLA_FLAGS=--xla_force_host_platform_device_count=P``
+so the parent benchmark process keeps its single device.  Any other run
+builds the mesh from the real devices in this process: a chip belongs
+to one process, and the parent already holds it.
 
-    PYTHONPATH=src python -m benchmarks.run --only coop_shard
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.run --only coop_shard
     PYTHONPATH=src python -m benchmarks.bench_coop_shard --worker  # in-proc
 """
 from __future__ import annotations
@@ -66,7 +68,10 @@ def _worker(fast: bool) -> dict:
     from repro.core.partition import ownership_balance
     from repro.engine import EngineConfig, MinibatchEngine
 
-    assert len(jax.devices()) >= P, "worker needs the forced device count"
+    if len(jax.devices()) < P:
+        raise RuntimeError(
+            f"coop_shard needs {P} devices, jax sees {len(jax.devices())}"
+        )
     g = bench_graph()
     shapes = SHAPES[:1] if fast else SHAPES
     payload = {
@@ -148,8 +153,8 @@ def _worker(fast: bool) -> dict:
     return payload
 
 
-def run(fast: bool = False) -> Csv:
-    """Re-exec in a forced-multi-device subprocess, collect the snapshot."""
+def _forced_host_worker(fast: bool) -> dict:
+    """CPU only: run the worker in a child with P forced host devices."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={P}"
     env["PYTHONPATH"] = "src" + (
@@ -167,7 +172,18 @@ def run(fast: bool = False) -> Csv:
             f"coop_shard worker failed:\n{proc.stderr[-4000:]}"
         )
     with open(os.path.join(repo, OUT_JSON)) as f:
-        payload = json.load(f)
+        return json.load(f)
+
+
+def run(fast: bool = False) -> Csv:
+    """Collect the snapshot: forced host devices in a child on the CPU,
+    the real devices in this process everywhere else."""
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        payload = _forced_host_worker(fast)
+    else:
+        payload = _worker(fast)
+        with open(OUT_JSON, "w") as f:
+            json.dump(payload, f, indent=2, sort_keys=True)
     csv = Csv(["key", "coop_fetch_rows", "indep_fetch_rows",
                "a2a_first_layer_rows", "fetch_win", "edge_balance"],
               snapshot=payload)

@@ -2,6 +2,10 @@
 
     PYTHONPATH=src python -m benchmarks.run [--only <name>] [--fast]
 
+On a CPU host set ``JAX_PLATFORMS=cpu`` explicitly: ``coop_shard`` then
+runs its 4-PE mesh on forced host devices in a child process; anywhere
+else every section runs in this one process on the real devices.
+
 Sections:
     monotonicity   Fig 3/6  (Thm 3.1/3.2 work curves per sampler)
     density        Thm 3.3  (induced-subgraph density vs batch)
@@ -68,6 +72,11 @@ def main() -> None:
     ap.add_argument("--skip-analysis", action="store_true",
                     help="skip the static-analysis gate / BENCH_analysis.json")
     args = ap.parse_args()
+
+    from repro.utils.compile_cache import enable_compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    enable_compile_cache(repo)
 
     if not args.skip_analysis:
         _section("analysis")

@@ -15,10 +15,13 @@ import warnings
 from dataclasses import dataclass, field, replace
 from typing import Optional
 
+from repro.kernels.errors import require_compiled
+
 MODES = ("independent", "cooperative")
 SCHEDULES = ("iid", "smoothed", "nested")
 EXECUTORS = ("sim", "shard")
 PLAN_BACKENDS = ("reference", "fused")
+FUSED_PLAN_KERNELS = ("unique_compact", "frontier_gather", "expand_indptr")
 
 _UNSET = object()  # sentinel distinguishing "not passed" for legacy kwargs
 
@@ -75,7 +78,9 @@ class EngineConfig:
     # how plan construction lowers: "reference" keeps the jnp
     # sort/searchsorted frontier algebra; "fused" routes the hot loop
     # through the Pallas kernels (unique_compact / frontier_gather /
-    # expand_indptr).  Bit-identical outputs either way.
+    # expand_indptr).  Bit-identical outputs either way.  On the TPU,
+    # "fused" raises KernelUnavailableError while one of those kernels
+    # does not compile there (frontier_gather today).
     plan_backend: str = "reference"
     cache: Optional[CacheConfig] = None
     # deprecated flat aliases for ``cache`` — kept so old configs keep
@@ -100,6 +105,9 @@ class EngineConfig:
                 f"plan_backend must be one of {PLAN_BACKENDS}, "
                 f"got {self.plan_backend!r}"
             )
+        if self.plan_backend == "fused":
+            for kernel in FUSED_PLAN_KERNELS:
+                require_compiled(kernel)
         if self.num_pes < 1 or self.local_batch < 1 or self.num_layers < 1:
             raise ValueError("num_pes, local_batch, num_layers must be >= 1")
         if self.schedule == "nested" and not self.kappa:

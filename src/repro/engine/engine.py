@@ -19,7 +19,7 @@ whole schedule.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import cached_property
 from typing import Optional
 
@@ -129,6 +129,32 @@ class MinibatchEngine:
             config=cfg, graph=graph, sampler=sampler, caps=caps, ex=ex,
             part=part, dataset=dataset, store=store, tiered=tiered,
         )
+
+    # ------------------------------------------------------------------
+    # Device arrays, as arguments of a jitted step
+    # ------------------------------------------------------------------
+    def device_arrays(self) -> tuple:
+        """The arrays a plan and its feature fetch read: graph, owner
+        map, seed pool table and features.  A jitted step should take
+        them as arguments: closed over, XLA embeds them in the executable
+        as constants (gigabytes at published sizes)."""
+        return (
+            self.graph,
+            None if self.part is None else self.part.owner,
+            self._seed_rows,
+            None if self.store is None else self.store.features,
+        )
+
+    def with_arrays(self, graph, owner, seed_rows, features) -> "MinibatchEngine":
+        """This engine over other arrays of the shapes
+        :meth:`device_arrays` returns, e.g. a jitted step's tracers."""
+        eng = replace(
+            self, graph=graph,
+            part=None if owner is None else replace(self.part, owner=owner),
+            store=None if features is None else FeatureStore(features),
+        )
+        eng.__dict__["_seed_rows"] = seed_rows  # fills the cached_property
+        return eng
 
     # ------------------------------------------------------------------
     # RNG schedule

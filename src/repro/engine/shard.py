@@ -38,7 +38,6 @@ from typing import TYPE_CHECKING, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.cooperative import (
@@ -107,9 +106,9 @@ class ShardRunner:
             mb = self._build_local(seeds_p, rng)
             return jax.tree.map(lambda x: x[None], mb)
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=self.mesh, in_specs=(P(ax), P()), out_specs=P(ax),
-            check_rep=False,
+            check_vma=False,
         )
 
         def build(step):
@@ -166,19 +165,20 @@ class ShardRunner:
             )
             loss = jax.lax.psum(share, ax)   # global masked-mean CE
             grads = jax.lax.psum(grads, ax)  # explicit gradient sync
-            return jax.tree.map(lambda x: x[None], (loss, grads))
+            return loss, grads
 
-        f = shard_map(
+        # psum'd outputs are replicated over the axis, so they leave the
+        # map unstacked (out_specs=P()).  check_vma stays off: with it on,
+        # AD over the replicated params inserts its own all-reduce, which
+        # the explicit sync above does not expect (the gradients came out
+        # at 1/P of the sim oracle's in tests/test_coop_shard.py).
+        f = jax.shard_map(
             body, mesh=self.mesh, in_specs=(P(), P(ax), P()),
-            out_specs=P(ax), check_rep=False,
+            out_specs=P(), check_vma=False,
         )
 
         def loss_and_grad(params, step):
             step = jnp.asarray(step, jnp.int32)
-            loss, grads = f(
-                params, eng._seed_batch_traced(step), eng.rng_state(step)
-            )
-            # outputs are replicated across the axis; take PE 0's copy
-            return loss[0], jax.tree.map(lambda x: x[0], grads)
+            return f(params, eng._seed_batch_traced(step), eng.rng_state(step))
 
         return loss_and_grad

@@ -34,7 +34,7 @@ def expand_indptr_pallas(
     indptr: jax.Array,  # (R+1,) int32 ascending
     num_edges: int,     # output length, % block_e == 0
     *,
-    block_e: int = 512,
+    block_e: int = 1024,
     interpret: bool = False,
 ) -> jax.Array:
     """(num_edges,) int32 row ids, -1 at or beyond indptr[-1]."""

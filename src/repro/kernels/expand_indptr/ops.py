@@ -11,15 +11,17 @@ def expand_indptr(
     indptr: jax.Array,
     num_edges: int,
     *,
-    block_e: int = 512,
+    block_e: int = 1024,
 ) -> jax.Array:
     """(num_edges,) int32 row id per edge slot, -1 past indptr[-1].
 
     Dispatches to the Pallas kernel on TPU, to the searchsorted oracle
-    elsewhere.  ``num_edges`` that is not a block multiple falls back to
-    the reference (plan capacities are caller-chosen powers of two, so
-    this only triggers for odd ad-hoc shapes).
+    elsewhere.  The kernel runs on ``num_edges`` rounded up to a block
+    multiple; slots past ``num_edges`` are sliced off (each slot's row
+    depends only on ``indptr``, so padding cannot perturb the rest).
+    ``block_e`` is a multiple of 1024, the TPU's 1-D int32 tile.
     """
-    if jax.default_backend() != "tpu" or num_edges % block_e != 0:
+    if jax.default_backend() != "tpu":
         return expand_indptr_ref(indptr, num_edges)
-    return expand_indptr_pallas(indptr, num_edges, block_e=block_e)
+    padded = num_edges + (-num_edges) % block_e
+    return expand_indptr_pallas(indptr, padded, block_e=block_e)[:num_edges]

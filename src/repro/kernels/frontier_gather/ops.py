@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.errors import require_compiled
 from repro.kernels.frontier_gather.kernel import frontier_gather_pallas
 from repro.kernels.frontier_gather.ref import frontier_gather_ref
 
@@ -22,13 +23,16 @@ def frontier_gather(
 ) -> tuple[jax.Array, jax.Array]:
     """(nbr (n, max_degree), mask) — bit-identical to the jnp oracle.
 
-    Dispatches to the paged Pallas sweep on TPU, to the reference
-    elsewhere.  Seeds pad with INVALID (contributing all-masked rows)
-    and indices pad freely (padded edges are never inside any valid
-    ``[offs, offs+deg)`` row slice), so blocking cannot perturb output.
+    Dispatches to the paged Pallas sweep on TPU (which raises
+    :class:`~repro.kernels.errors.KernelUnavailableError` until the
+    kernel compiles there), to the reference elsewhere.  Seeds pad with
+    INVALID (contributing all-masked rows) and indices pad freely
+    (padded edges are never inside any valid ``[offs, offs+deg)`` row
+    slice), so blocking cannot perturb output.
     """
     if jax.default_backend() != "tpu":
         return frontier_gather_ref(indptr, indices, seeds, max_degree)
+    require_compiled("frontier_gather")
     (n,) = seeds.shape
     (E,) = indices.shape
     pad_n = (-n) % block_n

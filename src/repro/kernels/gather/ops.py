@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.errors import require_compiled
 from repro.kernels.gather.kernel import paged_gather_pallas
 from repro.kernels.gather.ref import gather_ref
 
@@ -16,9 +17,14 @@ def paged_gather(
     block_d: int = 128,
     page: int = 2048,
 ) -> jax.Array:
-    """Masked embedding gather; INVALID / out-of-range ids produce zeros."""
+    """Masked embedding gather; INVALID / out-of-range ids produce zeros.
+
+    Raises :class:`~repro.kernels.errors.KernelUnavailableError` on the
+    TPU until the kernel compiles there.
+    """
     if jax.default_backend() != "tpu":
         return gather_ref(table, ids)
+    require_compiled("paged_gather")
     V, d = table.shape
     n = ids.shape[0]
     pad_v = (-V) % page

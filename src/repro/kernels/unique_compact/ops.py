@@ -15,7 +15,7 @@ def unique_with_inverse(
     ids: jax.Array,
     cap: int,
     *,
-    block_m: int = 256,
+    block_m: int = 4096,
 ) -> tuple[jax.Array, jax.Array]:
     """(uniq (cap,), inv (m,)) of a flat int32 id vector.
 
@@ -38,7 +38,7 @@ def unique_with_inverse(
     return uniq, inv[:m]
 
 
-def unique_compact(ids: jax.Array, cap: int, *, block_m: int = 256) -> jax.Array:
+def unique_compact(ids: jax.Array, cap: int, *, block_m: int = 4096) -> jax.Array:
     """Sorted unique ids with INVALID padding (fused unique only)."""
     uniq, _ = unique_with_inverse(ids, cap, block_m=block_m)
     return uniq

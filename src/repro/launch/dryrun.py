@@ -28,7 +28,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import ALL_ARCHS, get_config  # noqa: E402
 from repro.launch import roofline as rl  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import (  # noqa: E402
+    PRODUCTION_DEVICE_KIND,
+    make_production_mesh,
+)
 from repro.launch.shardings import (  # noqa: E402
     data_spec,
     decode_state_shardings,
@@ -151,7 +154,7 @@ def lower_combo(
     mem = compiled.memory_analysis()
     n_dev = mesh.size
     mf = rl.model_flops(cfg, spec, active_param_count(get_config(arch)))
-    roof = rl.analyze(compiled, n_dev, mf)
+    roof = rl.analyze(compiled, n_dev, mf, PRODUCTION_DEVICE_KIND)
     result = {
         "arch": arch,
         "shape": shape_name,

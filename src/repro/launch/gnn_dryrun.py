@@ -255,9 +255,8 @@ def lower_gnn_coop_step(
     model: str = "gcn",
     tag: str = "",
 ) -> dict:
-    from jax.experimental.shard_map import shard_map
-
     from repro.launch import roofline as rl
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND
 
     scale = SCALE_MAG if model == "rgcn" else SCALE
     NPE = 256
@@ -301,7 +300,7 @@ def lower_gnn_coop_step(
                 etypes.reshape(-1) if rgcn else None,
             )
 
-        return shard_map(
+        return jax.shard_map(
             per_pe,
             mesh=mesh,
             in_specs=(
@@ -316,7 +315,7 @@ def lower_gnn_coop_step(
                 P("pod", "pe"),         # etypes (aligned with indices)
             ),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(params, opt, indptr, indices, v_start, feats, labels, seeds, etypes)
 
     t0 = time.time()
@@ -336,7 +335,7 @@ def lower_gnn_coop_step(
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     model_flops = 0.0  # GNN: flops are data-dependent; report HLO terms only
-    roof = rl.analyze(compiled, mesh.size, model_flops)
+    roof = rl.analyze(compiled, mesh.size, model_flops, PRODUCTION_DEVICE_KIND)
     result = {
         "arch": "gnn-coop-mag240M-rgcn" if rgcn else "gnn-coop-papers100M-gcn",
         "shape": f"b{scale['local_batch']}xP{NPE}",

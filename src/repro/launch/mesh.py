@@ -6,6 +6,11 @@ state (the dry-run must set XLA_FLAGS before any jax initialization).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+#: ``device_kind`` of the chips the production meshes stand for; the dry
+#: runs compile on host placeholder devices and take peaks from this kind
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,6 +43,10 @@ def make_coop_mesh(num_pes: int, axis_name: str = "data"):
         XLA_FLAGS=--xla_force_host_platform_device_count=P
 
     which is how CI exercises the real all-to-all path without TPUs.
+
+    The axis is ``Auto``: ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    under which plain indexing of a stacked ``(P, ...)`` output that is
+    sharded along the axis is a sharding type error.
     """
     avail = len(jax.devices())
     if avail < num_pes:
@@ -47,7 +56,9 @@ def make_coop_mesh(num_pes: int, axis_name: str = "data"):
             f"XLA_FLAGS=--xla_force_host_platform_device_count={num_pes} "
             f"before importing jax"
         )
-    return jax.make_mesh((num_pes,), (axis_name,))
+    return jax.make_mesh(
+        (num_pes,), (axis_name,), axis_types=(AxisType.Auto,)
+    )
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

@@ -1,10 +1,12 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
-Three terms per (arch × shape × mesh), TPU v5e constants:
+Three terms per (arch × shape × mesh), from the peak rates of the
+device the program was compiled for (:data:`PEAKS`, keyed by JAX's
+``device_kind``):
 
-    compute    = HLO_FLOPs_per_device   / 197e12  FLOP/s (bf16)
-    memory     = HLO_bytes_per_device   / 819e9   B/s HBM
-    collective = coll_bytes_per_device  / 50e9    B/s ICI per link
+    compute    = HLO_FLOPs_per_device   / peak FLOP/s (bf16)
+    memory     = HLO_bytes_per_device   / peak HBM B/s
+    collective = coll_bytes_per_device  / ICI B/s per link
 
 ``compiled.cost_analysis()`` reports the per-device SPMD program, so the
 per-device form above equals the brief's global/(chips × peak) form.
@@ -17,10 +19,37 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-# TPU v5e
-PEAK_FLOPS = 197e12      # bf16 FLOP/s per chip
-HBM_BW = 819e9           # B/s per chip
-ICI_BW = 50e9            # B/s per link
+
+@dataclass(frozen=True)
+class Peaks:
+    flops: float     # bf16 FLOP/s per chip
+    hbm_bw: float    # HBM B/s per chip
+    ici_bw: float    # ICI B/s per link
+    source: str
+
+
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+PEAKS = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of inter-chip interconnect over 4 links
+        ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """Peak rates of ``device_kind``; a device not in :data:`PEAKS` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak rates for device_kind={device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -123,26 +152,29 @@ class Roofline:
         }
 
 
-def analyze(compiled, num_devices: int, model_flops_global: float) -> Roofline:
+def analyze(
+    compiled, num_devices: int, model_flops_global: float, device_kind: str
+) -> Roofline:
     """Trip-count-weighted roofline terms from the compiled SPMD module.
 
-    Raw ``cost_analysis()`` counts scan bodies once; the HLO walk in
+    ``device_kind`` names the chip the terms are for (the dry runs
+    compile on placeholder host devices that stand in for it).  Raw
+    ``cost_analysis()`` counts scan bodies once; the HLO walk in
     ``hlo_analysis`` re-weights by ``known_trip_count`` so scanned layer
     units / flash key-blocks / CE chunks are charged per execution.
     """
     from repro.launch.hlo_analysis import analyze_hlo
 
+    peaks = peaks_for(device_kind)
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # some jax versions return [dict]
-        cost = cost[0]
     raw_flops = float(cost.get("flops", 0.0))
     raw_hbm = float(cost.get("bytes accessed", 0.0))
     hlo = analyze_hlo(compiled.as_text())
     flops = max(hlo.dot_flops, raw_flops)
     hbm = max(hlo.hbm_bytes, raw_hbm)
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
-    collective_s = hlo.coll_bytes / ICI_BW
+    compute_s = flops / peaks.flops
+    memory_s = hbm / peaks.hbm_bw
+    collective_s = hlo.coll_bytes / peaks.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     mem_stats = compiled.memory_analysis()

@@ -13,7 +13,7 @@ by a clock hand over reference bits.  A batch access:
 
 1. dedups the batch (``jnp.unique`` with static ``size``),
 2. probes all ids against the tag array in one shot
-   (:func:`repro.store.kernel.tag_probe` — Pallas on TPU),
+   (:func:`repro.store.kernel.probe_ref`, XLA on every backend),
 3. sets the reference bit of every hit,
 4. inserts misses round-by-round (at most one insert per set per round,
    ``ways`` rounds total — a static Python loop), each round running
@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import INVALID
-from repro.store.kernel import tag_probe
+from repro.store.kernel import probe_ref
 
 _HASH_MULT = np.uint32(2654435761)  # Knuth multiplicative hashing
 
@@ -165,10 +165,12 @@ def clock_access(
     valid = uniq != INVALID
     sets = jnp.where(valid, hash_set(uniq, S), 0)
     # one flat probe for all PEs: offset each PE's sets into a (P*S, W)
-    # tag view so the Pallas kernel runs once, unbatched
+    # tag view so the probe runs once, unbatched.  It is the XLA probe on
+    # every backend: the tag_probe Pallas kernel does not compile for the
+    # TPU (see docs/kernels.md)
     gsets = sets + jnp.arange(P, dtype=jnp.int32)[:, None] * S
     pids = jnp.where(valid, uniq, -1)  # -1 never matches a resident tag
-    way = tag_probe(
+    way = probe_ref(
         state.tags.reshape(P * S, W), gsets.reshape(-1), pids.reshape(-1)
     ).reshape(P, -1)
     hit = way >= 0

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.errors import require_divisible
+from repro.kernels.errors import require_compiled, require_divisible
 
 
 def probe_ref(tags: jax.Array, sets: jax.Array, ids: jax.Array) -> jax.Array:
@@ -100,9 +100,13 @@ def tag_probe(
     block_n: int = 512,
     page: int = 1024,
 ) -> jax.Array:
-    """Batched cache-tag probe; dispatches to the kernel on TPU."""
+    """Batched cache-tag probe; dispatches to the kernel on TPU, which
+    raises :class:`~repro.kernels.errors.KernelUnavailableError` until
+    the kernel compiles there.  The CLOCK cache calls :func:`probe_ref`
+    on every backend."""
     if jax.default_backend() != "tpu":
         return probe_ref(tags, sets, ids)
+    require_compiled("tag_probe")
     S, W = tags.shape
     (n,) = ids.shape
     pad_s = (-S) % page

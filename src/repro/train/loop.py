@@ -17,16 +17,17 @@ engine, and supervises the seed frontier.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import frontier
 from repro.core.graph import INVALID
 from repro.engine import EngineConfig, MinibatchEngine
+from repro.engine.shard import ShardRunner
 from repro.models.gnn import GNNConfig, init_gnn
 from repro.train.metrics import masked_softmax_xent, micro_f1
 from repro.train.optim import adam_init, adam_update
@@ -95,26 +96,37 @@ def train_gnn(dataset, gnn_cfg: GNNConfig, tc: TrainConfig) -> TrainResult:
     engine = MinibatchEngine.from_config(
         dataset.graph, tc.engine_config(gnn_cfg.num_layers), dataset=dataset
     )
-    store, labels = engine.store, dataset.labels
-
     params = init_gnn(jax.random.PRNGKey(tc.seed), gnn_cfg)
     opt = adam_init(params)
-
-    if tc.executor == "shard" and tc.mode == "cooperative":
-        # real multi-device path: per-PE plan build + cooperative F/B run
-        # under shard_map on a P-device mesh, and gradient sync is an
-        # explicit jax.lax.psum over the same axis as the all-to-alls
-        loss_and_grad = engine.shard_runner.make_loss_and_grad(
-            gnn_cfg, store.features, labels
+    # graph, features and labels enter the step as arguments: closed
+    # over, XLA would embed them in the executable as constants
+    data = (engine.device_arrays(), jnp.asarray(dataset.labels))
+    shard = tc.executor == "shard" and tc.mode == "cooperative"
+    if shard:
+        mesh = engine.shard_runner.mesh
+        # start from the layout the step returns (replicated over the
+        # mesh), so the first step does not compile a second program
+        params, opt, data = jax.device_put(
+            (params, opt, data), NamedSharding(mesh, PartitionSpec())
         )
-    else:
-        loss_and_grad = jax.value_and_grad(
-            make_loss_fn(engine, gnn_cfg, store, labels)
-        )
 
-    @partial(jax.jit, static_argnums=())
-    def train_step(params, opt, step):
-        loss, grads = loss_and_grad(params, step)
+    def loss_and_grad(params, step, data):
+        arrays, labels = data
+        eng = engine.with_arrays(*arrays)
+        if shard:
+            # real multi-device path: per-PE plan build + cooperative F/B
+            # run under shard_map on a P-device mesh, and gradient sync is
+            # an explicit jax.lax.psum over the same axis as the all-to-alls
+            fn = ShardRunner.for_engine(eng, mesh).make_loss_and_grad(
+                gnn_cfg, eng.store.features, labels
+            )
+        else:
+            fn = jax.value_and_grad(make_loss_fn(eng, gnn_cfg, eng.store, labels))
+        return fn(params, step)
+
+    @jax.jit
+    def train_step(params, opt, step, data):
+        loss, grads = loss_and_grad(params, step, data)
         params, opt = adam_update(params, grads, opt, lr=tc.lr)
         return params, opt, loss
 
@@ -123,7 +135,7 @@ def train_gnn(dataset, gnn_cfg: GNNConfig, tc: TrainConfig) -> TrainResult:
         # `step` is a dynamic arg: seed draw and smoothed-RNG state
         # (z1, z2, c) are computed inside the compiled step, so one trace
         # serves the whole kappa schedule.
-        params, opt, loss = train_step(params, opt, jnp.int32(step))
+        params, opt, loss = train_step(params, opt, jnp.int32(step), data)
         result.losses.append(float(loss))
         if tc.eval_every and (step + 1) % tc.eval_every == 0:
             result.val_f1.append(evaluate(dataset, gnn_cfg, params, tc))

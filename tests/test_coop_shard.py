@@ -67,9 +67,12 @@ _SCRIPT = textwrap.dedent(
 
     # ---- 2. loss + psum-synced grads match the vmap oracle ---------------
     sim, sh = engines("smoothed", 3, "degree")
-    lg_sim = jax.value_and_grad(make_loss_fn(sim, gnn_cfg, sim.store, ds.labels))
-    lg_sh = sh.shard_runner.make_loss_and_grad(gnn_cfg, sh.store.features,
-                                               ds.labels)
+    # jitted as train_gnn runs them: one compile each instead of an
+    # eager dispatch (and shard_map retrace) on every call
+    lg_sim = jax.jit(jax.value_and_grad(
+        make_loss_fn(sim, gnn_cfg, sim.store, ds.labels)))
+    lg_sh = jax.jit(sh.shard_runner.make_loss_and_grad(
+        gnn_cfg, sh.store.features, ds.labels))
     for step in range(4):
         l1, g1 = lg_sim(params, jnp.int32(step))
         l2, g2 = lg_sh(params, jnp.int32(step))

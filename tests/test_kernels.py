@@ -223,3 +223,91 @@ def test_plan_kernel_ops_dispatch_to_ref_on_cpu():
         np.asarray(expand_indptr(iptr, 8)),
         np.asarray([0, 0, 2, 2, 2, -1, -1, -1]),
     )
+
+
+# ---------------------------------------------------------------------------
+# TPU dispatch: every ops wrapper runs its compiled kernel or raises, naming
+# it.  The backend is steered here, in the test, and kernels run in
+# interpret mode where one is due to run.
+# ---------------------------------------------------------------------------
+def _on_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_fused_plan_backend_raises_on_tpu(monkeypatch):
+    from repro.engine import EngineConfig
+    from repro.kernels.errors import KernelUnavailableError
+
+    _on_tpu(monkeypatch)
+    with pytest.raises(KernelUnavailableError, match="frontier_gather"):
+        EngineConfig(plan_backend="fused")
+    EngineConfig(plan_backend="reference")  # the default path is unaffected
+
+
+@pytest.mark.parametrize("num_edges", [1000, 1024, 2050])
+def test_expand_indptr_pads_on_tpu(monkeypatch, num_edges):
+    """A length that is not a block multiple runs the kernel on the padded
+    length and slices, instead of giving way to the oracle."""
+    import importlib
+
+    # the package attribute is shadowed by the function of the same name
+    ops = importlib.import_module("repro.kernels.expand_indptr.ops")
+
+    calls = []
+
+    def interpreted(indptr, n, *, block_e):
+        calls.append((n, block_e))
+        return expand_indptr_pallas(indptr, n, block_e=block_e, interpret=True)
+
+    _on_tpu(monkeypatch)
+    monkeypatch.setattr(ops, "expand_indptr_pallas", interpreted)
+    deg = np.random.default_rng(num_edges).integers(0, 9, size=200)
+    iptr = jnp.asarray(np.concatenate([[0], np.cumsum(deg)]).astype(np.int32))
+    got = ops.expand_indptr(iptr, num_edges)
+    assert calls == [(-(-num_edges // 1024) * 1024, 1024)]
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(expand_indptr_ref(iptr, num_edges))
+    )
+
+
+def _uncompiled_calls():
+    from repro import kernels
+    from repro.store.kernel import tag_probe
+
+    ids = jnp.zeros((8,), jnp.int32)
+    return {
+        "frontier_gather": lambda: kernels.frontier_gather(
+            jnp.zeros((9,), jnp.int32), ids, ids, 4),
+        "paged_gather": lambda: kernels.paged_gather(jnp.zeros((8, 4)), ids),
+        "spmm": lambda: kernels.spmm_mean(
+            jnp.zeros((8, 4)), jnp.zeros((8, 2), jnp.int32),
+            jnp.ones((8, 2), bool)),
+        "tag_probe": lambda: tag_probe(jnp.zeros((8, 2), jnp.int32), ids, ids),
+    }
+
+
+@pytest.mark.parametrize(
+    "kernel", ["frontier_gather", "paged_gather", "spmm", "tag_probe"]
+)
+def test_uncompiled_kernel_wrappers_raise_on_tpu(monkeypatch, kernel):
+    from repro.kernels.errors import UNCOMPILED_ON_TPU, KernelUnavailableError
+
+    assert kernel in UNCOMPILED_ON_TPU
+    call = _uncompiled_calls()[kernel]
+    call()  # off the TPU: the oracle
+    _on_tpu(monkeypatch)
+    with pytest.raises(KernelUnavailableError, match=kernel):
+        call()
+
+
+def test_clock_cache_probe_runs_on_tpu(monkeypatch):
+    """Serving's default cache probes with XLA on every backend."""
+    from repro.core.graph import INVALID
+    from repro.store import ClockCache
+
+    _on_tpu(monkeypatch)
+    # a shape no other test uses, so clock_access traces under the patch
+    cache = ClockCache(capacity=72, ways=8)
+    ids = np.array([[3, 5, 9, INVALID, 11]], np.int32)
+    assert cache.access_batch(ids) == 4
+    assert cache.access_batch(ids) == 0

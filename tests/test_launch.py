@@ -6,7 +6,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.roofline import model_flops
+from repro.launch.roofline import model_flops, peaks_for
 from repro.launch.specs import SHAPES, batch_specs, shape_applicable
 
 
@@ -94,3 +94,13 @@ def test_param_sharding_rules_small_mesh():
     assert sh["embed"].spec == P("model", None)
     assert sh["blocks"][0]["attn"]["wq"].spec == P(None, None, "model")
     assert sh["blocks"][0]["norm1"].spec == P(None, None)
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    """v5e's published peaks; a device without published peaks is an
+    error, not a silent v5e default."""
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(ValueError, match="cpu"):
+        peaks_for("cpu")

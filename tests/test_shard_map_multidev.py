@@ -16,7 +16,6 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core.cooperative import (
         CoopCapacityPlan, SimExecutor, ShardExecutor,
         build_cooperative_minibatch, redistribute)
@@ -63,9 +62,9 @@ _SCRIPT = textwrap.dedent(
         return Ht[None], mb.layers[L - 1].tilde_ids[None]
 
     with mesh:
-        f = shard_map(per_pe, mesh=mesh, in_specs=(P("data", None),),
-                      out_specs=(P("data", None, None), P("data", None)),
-                      check_rep=False)
+        f = jax.shard_map(per_pe, mesh=mesh, in_specs=(P("data", None),),
+                          out_specs=(P("data", None, None), P("data", None)),
+                          check_vma=False)
         Ht_sh, tid_sh = jax.jit(f)(seeds)
 
     # same tilde ids and same redistributed embeddings per PE
