@@ -126,7 +126,8 @@ class CoopMinibatch:
 
     def gather_inputs(self, store) -> jax.Array:
         """Owned input embeddings (no cross-PE duplication, Fig. 7b)."""
-        return store.gather(self.input_ids)
+        with jax.named_scope("fetch.inputs"):
+            return store.gather(self.input_ids)
 
     def stats(self) -> dict:
         """Per-PE max counts (Table 7).  Requires the stacked Sim layout."""
@@ -245,7 +246,8 @@ def build_cooperative_minibatch(
     def local_seeds(s):
         return frontier.unique_compact(s, caps.caps[0], backend=backend)
 
-    S_l = ex.pe(local_seeds, seeds)
+    with jax.named_scope("plan.seed_draw"):
+        S_l = ex.pe(local_seeds, seeds)
     layers = []
     for l in range(num_layers):
         cap_t, cap_b, cap_next = caps.tilde_caps[l], caps.bucket_caps[l], caps.caps[l + 1]
@@ -260,11 +262,6 @@ def build_cooperative_minibatch(
             bucket_ids, slot_to_tilde = _bucketize(tilde, owners, P, cap_b)
             return ls, tilde, nbr_idx, self_idx, bucket_ids, slot_to_tilde
 
-        ls, tilde, nbr_idx, self_idx, bucket_ids, slot_to_tilde = ex.pe(
-            sample_and_bucket, S_l
-        )
-        req = ex.exchange(bucket_ids)  # ids owned here, requested per peer
-
         def next_frontier(req):
             # one fused dedup resolves BOTH the next owned frontier and
             # every peer request slot — the separate lookup pass is gone
@@ -273,19 +270,25 @@ def build_cooperative_minibatch(
             )
             return S_next, inv.reshape(req.shape)
 
-        S_next, req_idx = ex.pe(next_frontier, req)
-        layers.append(
-            CoopLayer(
-                seeds=S_l,
-                self_idx=self_idx,
-                nbr_idx=nbr_idx,
-                mask=ls.mask & (nbr_idx >= 0),
-                etypes=ls.etypes,
-                slot_to_tilde=slot_to_tilde,
-                req_idx=req_idx,
-                tilde_ids=tilde,
+        with jax.named_scope(f"plan.hop{l + 1}"):
+            ls, tilde, nbr_idx, self_idx, bucket_ids, slot_to_tilde = ex.pe(
+                sample_and_bucket, S_l
             )
-        )
+            with jax.named_scope("exchange.ids"):
+                req = ex.exchange(bucket_ids)  # ids owned here, requested per peer
+            S_next, req_idx = ex.pe(next_frontier, req)
+            layers.append(
+                CoopLayer(
+                    seeds=S_l,
+                    self_idx=self_idx,
+                    nbr_idx=nbr_idx,
+                    mask=ls.mask & (nbr_idx >= 0),
+                    etypes=ls.etypes,
+                    slot_to_tilde=slot_to_tilde,
+                    req_idx=req_idx,
+                    tilde_ids=tilde,
+                )
+            )
         S_l = S_next
     seed_ids = layers[0].seeds
     return CoopMinibatch(layers=tuple(layers), input_ids=S_l, seed_ids=seed_ids)
@@ -309,7 +312,8 @@ def redistribute(
         return jnp.where((req_idx >= 0)[..., None], send, 0.0)
 
     send = ex.pe(gather_send, H, layer.req_idx)
-    recv = ex.exchange(send)
+    with jax.named_scope("exchange.embeddings"):
+        recv = ex.exchange(send)
 
     def scatter(recv, slot_to_tilde):
         d = recv.shape[-1]

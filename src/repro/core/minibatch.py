@@ -61,7 +61,8 @@ class Minibatch:
 
     def gather_inputs(self, store) -> jax.Array:
         """Input-layer embeddings from a :class:`FeatureStore`-like object."""
-        return store.gather(self.input_ids)
+        with jax.named_scope("fetch.inputs"):
+            return store.gather(self.input_ids)
 
     def stats(self) -> dict:
         """Uniform per-layer counts: S{l}, E{l}, inputs, comm{l+1} (=0).
@@ -142,23 +143,27 @@ def build_minibatch(
     sweep (Pallas on TPU).  Outputs are bit-identical.
     """
     frontier._check_backend(backend)
-    S_l = frontier.unique_compact(seeds, caps[0], backend=backend)
+    with jax.named_scope("plan.seed_draw"):
+        S_l = frontier.unique_compact(seeds, caps[0], backend=backend)
     layers = []
     for l in range(num_layers):
-        ls = sampler.sample_layer(graph, S_l, rng, l)
-        cat = jnp.concatenate([S_l, ls.nbr.reshape(-1)])
-        S_next, inv = frontier.unique_with_inverse(cat, caps[l + 1], backend=backend)
-        self_idx = inv[: S_l.shape[0]]
-        nbr_idx = inv[S_l.shape[0]:].reshape(ls.nbr.shape)
-        layers.append(
-            MinibatchLayer(
-                seeds=S_l,
-                self_idx=self_idx,
-                nbr_idx=nbr_idx,
-                mask=ls.mask & (nbr_idx >= 0),
-                etypes=ls.etypes,
+        with jax.named_scope(f"plan.hop{l + 1}"):
+            ls = sampler.sample_layer(graph, S_l, rng, l)
+            cat = jnp.concatenate([S_l, ls.nbr.reshape(-1)])
+            S_next, inv = frontier.unique_with_inverse(
+                cat, caps[l + 1], backend=backend
             )
-        )
+            self_idx = inv[: S_l.shape[0]]
+            nbr_idx = inv[S_l.shape[0]:].reshape(ls.nbr.shape)
+            layers.append(
+                MinibatchLayer(
+                    seeds=S_l,
+                    self_idx=self_idx,
+                    nbr_idx=nbr_idx,
+                    mask=ls.mask & (nbr_idx >= 0),
+                    etypes=ls.etypes,
+                )
+            )
         S_l = S_next
     return Minibatch(layers=tuple(layers), input_ids=S_l, seed_ids=layers[0].seeds)
 

@@ -172,6 +172,7 @@ class MinibatchEngine:
             return self._nested_sched().rng_for_group(step)  # frozen per group
         return DependentRNG(cfg.seed, cfg.effective_kappa, step)
 
+    @jax.named_scope("plan.seed_draw")
     def rng_state(self, step) -> RNGState:
         """Traceable RNG state — ``step`` may be a traced int32 scalar, so
         a single compiled train step covers the whole κ schedule."""
@@ -221,7 +222,12 @@ class MinibatchEngine:
         need = cfg.kappa * b if cfg.schedule == "nested" else (
             P * b if len(rows) == 1 else b
         )
-        C = max(need, max(len(r) for r in rows))
+        # every row pads to the whole pool, not to the largest row: a
+        # cooperative row is what one PE owns of the train ids, which
+        # moves with the data, and a width that moved with it would
+        # compile a train step per dataset.  Padding sorts last in the
+        # draw, so the seeds do not depend on the width.
+        C = max(need, len(self._seed_pool()))
         out = np.full((len(rows), C), np.int32(INVALID), np.int32)
         for i, r in enumerate(rows):
             out[i, : len(r)] = np.asarray(r, np.int32)
@@ -230,6 +236,7 @@ class MinibatchEngine:
         with jax.ensure_compile_time_eval():
             return jnp.asarray(out)
 
+    @jax.named_scope("plan.seed_draw")
     def _seed_batch_traced(self, step) -> jax.Array:
         """(P, b) int32 seed rows for a (possibly traced) ``step``.
 
@@ -356,7 +363,8 @@ class MinibatchEngine:
         paper's §4.2 bandwidth saving, served rather than simulated.
         """
         if self.tiered is not None:
-            return self.tiered.gather(plan.input_ids)
+            with jax.named_scope("fetch.inputs"):
+                return self.tiered.gather(plan.input_ids)
         if self.store is None:
             raise ValueError(
                 "engine has no feature store; construct with a dataset"

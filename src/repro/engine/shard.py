@@ -147,24 +147,29 @@ class ShardRunner:
 
         def local_share(params, seeds_p, rng):
             mb = self._build_local(seeds_p, rng)
-            h = features[jnp.clip(mb.input_ids, 0, V - 1)]
-            H = jnp.where((mb.input_ids != INVALID)[:, None], h, 0.0)
+            with jax.named_scope("fetch.inputs"):
+                h = features[jnp.clip(mb.input_ids, 0, V - 1)]
+                H = jnp.where((mb.input_ids != INVALID)[:, None], h, 0.0)
             logits = gnn_apply_cooperative(
                 params, gnn_cfg, ex, mb.layers, H, eng.caps.tilde_caps
             )
-            y = labels[jnp.clip(mb.seed_ids, 0, V - 1)]
-            valid = mb.seed_ids != INVALID
-            s, n = masked_softmax_xent_parts(logits, y, valid)
-            # this PE's share of the global masked mean: CE sum over the
-            # *global* valid count; psum of shares == the global mean
-            return s / jnp.maximum(jax.lax.psum(n, ax), 1).astype(s.dtype)
+            with jax.named_scope("gnn.loss"):
+                y = labels[jnp.clip(mb.seed_ids, 0, V - 1)]
+                valid = mb.seed_ids != INVALID
+                s, n = masked_softmax_xent_parts(logits, y, valid)
+                # this PE's share of the global masked mean: CE sum over
+                # the *global* valid count; psum of shares == the mean
+                with jax.named_scope("exchange.grads"):
+                    n = jax.lax.psum(n, ax)
+                return s / jnp.maximum(n, 1).astype(s.dtype)
 
         def body(params, seeds_p, rng):
             share, grads = jax.value_and_grad(local_share)(
                 params, seeds_p, rng
             )
-            loss = jax.lax.psum(share, ax)   # global masked-mean CE
-            grads = jax.lax.psum(grads, ax)  # explicit gradient sync
+            with jax.named_scope("exchange.grads"):
+                loss = jax.lax.psum(share, ax)   # global masked-mean CE
+                grads = jax.lax.psum(grads, ax)  # explicit gradient sync
             return loss, grads
 
         # psum'd outputs are replicated over the axis, so they leave the

@@ -32,9 +32,11 @@ def run_gnn(args) -> None:
                      kappa=args.kappa, sampler=args.sampler,
                      partition=args.partition,
                      eval_every=max(args.steps // 5, 1))
-    t0 = time.time()
     r = train_gnn(ds, cfg, tc)
-    print(f"[{args.mode}] {args.steps} steps in {time.time()-t0:.1f}s  "
+    # step_s[0] holds the step's compile; the median is the steady step
+    print(f"[{args.mode}] {args.steps} steps, step median "
+          f"{1e3 * np.median(r.step_s):.1f} ms, max {1e3 * max(r.step_s):.1f} ms, "
+          f"traced {r.step_traces}x  "
           f"loss {r.losses[0]:.3f}->{np.mean(r.losses[-5:]):.3f}  "
           f"val_f1={r.val_f1}")
     print(f"test_f1={evaluate(ds, cfg, r.params, tc, split='test'):.3f}")

@@ -155,11 +155,12 @@ def gnn_apply(
     H = H_input
     for l in reversed(range(cfg.num_layers)):
         blk = plan_layers[l]
-        Ht = provide(l, H)
-        H = layer_apply(
-            params["layers"][l], cfg, l, Ht, blk.self_idx, blk.nbr_idx, blk.mask,
-            blk.etypes,
-        )
+        with jax.named_scope(f"gnn.layer{l}"):
+            Ht = provide(l, H)
+            H = layer_apply(
+                params["layers"][l], cfg, l, Ht, blk.self_idx, blk.nbr_idx,
+                blk.mask, blk.etypes,
+            )
     return H
 
 
@@ -183,19 +184,15 @@ def gnn_apply_cooperative(
     H = H_input
     for l in reversed(range(cfg.num_layers)):
         blk = plan_layers[l]
-        Ht = redistribute(ex, blk, H, tilde_caps[l])
         p_l = params["layers"][l]
 
-        if blk.etypes is None:
-            def apply_one(Ht, si, ni, mk, _p=p_l, _l=l):
-                return layer_apply(_p, cfg, _l, Ht, si, ni, mk, None)
+        def apply_one(Ht, si, ni, mk, et=None, _p=p_l, _l=l):
+            return layer_apply(_p, cfg, _l, Ht, si, ni, mk, et)
 
-            H = ex.pe(apply_one, Ht, blk.self_idx, blk.nbr_idx, blk.mask)
-        else:
-            def apply_one_et(Ht, si, ni, mk, et, _p=p_l, _l=l):
-                return layer_apply(_p, cfg, _l, Ht, si, ni, mk, et)
-
-            H = ex.pe(
-                apply_one_et, Ht, blk.self_idx, blk.nbr_idx, blk.mask, blk.etypes
-            )
+        with jax.named_scope(f"gnn.layer{l}"):
+            args = (redistribute(ex, blk, H, tilde_caps[l]),
+                    blk.self_idx, blk.nbr_idx, blk.mask)
+            if blk.etypes is not None:
+                args += (blk.etypes,)
+            H = ex.pe(apply_one, *args)
     return H

@@ -16,6 +16,7 @@ engine, and supervises the seed frontier.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -65,6 +66,10 @@ class TrainResult:
     params: dict
     losses: list = field(default_factory=list)
     val_f1: list = field(default_factory=list)
+    # host seconds of each step, from dispatch to the loss read-back
+    step_s: list = field(default_factory=list)
+    # times the step's body was traced: 1 unless something recompiled it
+    step_traces: int = 0
 
 
 def make_loss_fn(engine: MinibatchEngine, gnn_cfg: GNNConfig, store, labels):
@@ -83,32 +88,42 @@ def make_loss_fn(engine: MinibatchEngine, gnn_cfg: GNNConfig, store, labels):
         plan = engine.plan_at(step)
         H = plan.gather_inputs(store)
         logits = engine.apply_model(params, gnn_cfg, plan, H)
-        y = labels[jnp.clip(plan.seed_ids, 0, V - 1)]
-        valid = plan.seed_ids != INVALID
-        return masked_softmax_xent(
-            logits.reshape(-1, logits.shape[-1]), y.reshape(-1), valid.reshape(-1)
-        )
+        with jax.named_scope("gnn.loss"):
+            y = labels[jnp.clip(plan.seed_ids, 0, V - 1)]
+            valid = plan.seed_ids != INVALID
+            return masked_softmax_xent(
+                logits.reshape(-1, logits.shape[-1]), y.reshape(-1),
+                valid.reshape(-1),
+            )
 
     return loss_fn
 
 
 def train_gnn(dataset, gnn_cfg: GNNConfig, tc: TrainConfig) -> TrainResult:
-    engine = MinibatchEngine.from_config(
-        dataset.graph, tc.engine_config(gnn_cfg.num_layers), dataset=dataset
-    )
-    params = init_gnn(jax.random.PRNGKey(tc.seed), gnn_cfg)
-    opt = adam_init(params)
-    # graph, features and labels enter the step as arguments: closed
-    # over, XLA would embed them in the executable as constants
-    data = (engine.device_arrays(), jnp.asarray(dataset.labels))
+    """Train for ``tc.num_steps`` steps of one jitted ``train_step``.
+
+    Under ``jax.profiler.trace`` the call leaves host spans
+    ``train_gnn.setup``, ``train_gnn.step`` (one per step, with its step
+    number) and ``train_gnn.eval``; the step's device ops carry the
+    named scopes listed in docs/architecture.md ("Tracing").
+    """
     shard = tc.executor == "shard" and tc.mode == "cooperative"
-    if shard:
-        mesh = engine.shard_runner.mesh
-        # start from the layout the step returns (replicated over the
-        # mesh), so the first step does not compile a second program
-        params, opt, data = jax.device_put(
-            (params, opt, data), NamedSharding(mesh, PartitionSpec())
+    with jax.profiler.TraceAnnotation("train_gnn.setup"):
+        engine = MinibatchEngine.from_config(
+            dataset.graph, tc.engine_config(gnn_cfg.num_layers), dataset=dataset
         )
+        params = init_gnn(jax.random.PRNGKey(tc.seed), gnn_cfg)
+        opt = adam_init(params)
+        # graph, features and labels enter the step as arguments: closed
+        # over, XLA would embed them in the executable as constants
+        data = (engine.device_arrays(), jnp.asarray(dataset.labels))
+        if shard:
+            mesh = engine.shard_runner.mesh
+            # start from the layout the step returns (replicated over the
+            # mesh), so the first step does not compile a second program
+            params, opt, data = jax.device_put(
+                (params, opt, data), NamedSharding(mesh, PartitionSpec())
+            )
 
     def loss_and_grad(params, step, data):
         arrays, labels = data
@@ -124,21 +139,28 @@ def train_gnn(dataset, gnn_cfg: GNNConfig, tc: TrainConfig) -> TrainResult:
             fn = jax.value_and_grad(make_loss_fn(eng, gnn_cfg, eng.store, labels))
         return fn(params, step)
 
+    result = TrainResult(params=params)
+
     @jax.jit
     def train_step(params, opt, step, data):
+        result.step_traces += 1
         loss, grads = loss_and_grad(params, step, data)
-        params, opt = adam_update(params, grads, opt, lr=tc.lr)
+        with jax.named_scope("optim.update"):
+            params, opt = adam_update(params, grads, opt, lr=tc.lr)
         return params, opt, loss
 
-    result = TrainResult(params=params)
     for step in range(tc.num_steps):
         # `step` is a dynamic arg: seed draw and smoothed-RNG state
         # (z1, z2, c) are computed inside the compiled step, so one trace
         # serves the whole kappa schedule.
-        params, opt, loss = train_step(params, opt, jnp.int32(step), data)
-        result.losses.append(float(loss))
+        with jax.profiler.StepTraceAnnotation("train_gnn.step", step_num=step):
+            t = time.perf_counter()
+            params, opt, loss = train_step(params, opt, jnp.int32(step), data)
+            result.losses.append(float(loss))
+            result.step_s.append(time.perf_counter() - t)
         if tc.eval_every and (step + 1) % tc.eval_every == 0:
-            result.val_f1.append(evaluate(dataset, gnn_cfg, params, tc))
+            with jax.profiler.TraceAnnotation("train_gnn.eval"):
+                result.val_f1.append(evaluate(dataset, gnn_cfg, params, tc))
         result.params = params
     return result
 

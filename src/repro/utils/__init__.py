@@ -1,4 +1,3 @@
 from repro.utils.logging import get_logger
-from repro.utils.timing import Timer
 
-__all__ = ["get_logger", "Timer"]
+__all__ = ["get_logger"]

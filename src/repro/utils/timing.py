@@ -1,34 +1,7 @@
-"""Wall-clock timing helpers used by the benchmark harness."""
+"""Wall-clock timing of a jitted call, for the kernel microbenchmarks."""
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
-
-
-@dataclass
-class Timer:
-    """Accumulating timer; use as a context manager around hot regions."""
-
-    name: str = "timer"
-    total_s: float = 0.0
-    count: int = 0
-    _t0: float = field(default=0.0, repr=False)
-
-    def __enter__(self) -> "Timer":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.total_s += time.perf_counter() - self._t0
-        self.count += 1
-
-    @property
-    def mean_us(self) -> float:
-        return 1e6 * self.total_s / max(1, self.count)
-
-    def reset(self) -> None:
-        self.total_s = 0.0
-        self.count = 0
 
 
 def bench_fn(fn, *args, warmup: int = 1, iters: int = 5) -> float:

@@ -206,6 +206,34 @@ def test_cooperative_seed_rows_stay_owned(small_graph):
             assert (owner[row] == p).all()
 
 
+@pytest.mark.parametrize("schedule", ["iid", "nested"])
+def test_cooperative_pool_width_is_the_pool(small_graph, schedule):
+    """The cooperative pool table is as wide as the train pool, whatever
+    share each PE owns, so a train step compiled for one dataset serves
+    another of the same size; the draw equals one from a table padded
+    only to the largest share."""
+    from repro.data.synthetic import SyntheticGraphDataset
+    from repro.engine.engine import _hash_permute_rows
+
+    widths = set()
+    for data_seed in (0, 1):
+        ds = SyntheticGraphDataset(small_graph, feature_dim=4, num_classes=3,
+                                   seed=data_seed)
+        cfg = EngineConfig(mode="cooperative", num_pes=2, local_batch=16,
+                           num_layers=2, fanout=4, schedule=schedule,
+                           kappa=2, seed=3)
+        eng = MinibatchEngine.from_config(small_graph, cfg, dataset=ds)
+        rows = np.asarray(eng._seed_rows)
+        widths.add(rows.shape[1])
+        assert rows.shape[1] == len(ds.train_ids)
+        narrow = rows[:, : max(len(r) for r in eng._owned_pools)]
+        z = jnp.uint32(12345)
+        np.testing.assert_array_equal(
+            np.asarray(_hash_permute_rows(jnp.asarray(rows), z))[:, : narrow.shape[1]],
+            np.asarray(_hash_permute_rows(jnp.asarray(narrow), z)))
+    assert len(widths) == 1
+
+
 # ---------------------------------------------------------------------------
 # CacheConfig migration
 # ---------------------------------------------------------------------------
