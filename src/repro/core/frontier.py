@@ -3,9 +3,11 @@
 JAX/TPU cannot lower dynamic-size frontiers, so every expansion set
 ``S^l`` is a fixed-capacity int32 vector padded with ``INVALID`` and kept
 *sorted* (valid ids first, then padding — INVALID is int32 max so a plain
-sort yields this layout).  All set algebra (union, unique, membership)
-reduces to sorts and searchsorted, which lower to efficient TPU sort
-networks.
+sort yields this layout).  The plan builders' dedup-and-rank
+(:func:`unique_with_inverse`) is one key-value sort, a cumulative sum and
+two scatters, with no search; :func:`lookup` (a binary search, a while
+loop on the TPU) and :func:`contains` stay as its independent test
+oracle.
 """
 from __future__ import annotations
 
@@ -59,9 +61,9 @@ def unique_with_inverse(
 
     ``uniq`` equals :func:`unique_padded` and ``inv`` equals
     :func:`lookup` of the flattened input against it — both backends are
-    bit-identical; ``"fused"`` routes through the
-    :mod:`repro.kernels.unique_compact` sweep (one pass over sorted data
-    instead of ``jnp.unique`` plus two ``searchsorted``).
+    bit-identical.  ``"reference"`` is :func:`sort_unique_with_inverse`;
+    ``"fused"`` routes through the :mod:`repro.kernels.unique_compact`
+    sweep (Pallas on TPU).
     """
     _check_backend(backend)
     flat = ids.reshape(-1)
@@ -69,8 +71,37 @@ def unique_with_inverse(
         from repro import kernels
 
         return kernels.unique_with_inverse(flat, cap)
-    uniq = unique_padded(flat, cap)
-    return uniq, lookup(uniq, flat)
+    return sort_unique_with_inverse(flat, cap)
+
+
+@partial(jax.jit, static_argnums=(1,))
+def sort_unique_with_inverse(ids: jax.Array, cap: int) -> tuple[jax.Array, jax.Array]:
+    """Dedup and rank a flat id vector with one key-value sort.
+
+    Sorting ``(ids, iota)`` on the ids yields the sorted ids and the
+    permutation that sorts them; first-occurrence flags and their
+    cumulative sum give every sorted id its rank in the result, and the
+    permutation carries each rank back to its input position — no
+    search.  INVALID sorts last like any value; ids of rank >= ``cap``
+    (the overflow policy of :func:`unique_padded`) and INVALID map to -1.
+    """
+    flat = ids.reshape(-1)
+    m = flat.shape[0]
+    s, order = jax.lax.sort(
+        (flat, jnp.arange(m, dtype=jnp.int32)), num_keys=1, is_stable=False
+    )
+    first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
+    rank = jnp.cumsum(first, dtype=jnp.int32) - 1
+    # rank >= cap parks in slot `cap`, sliced off below; all writers of a
+    # slot < cap carry the same value, so the duplicate scatter is exact
+    slot = jnp.where(rank < cap, rank, cap)
+    uniq = jnp.full((cap + 1,), INVALID, flat.dtype).at[slot].set(s)[:cap]
+    inv_sorted = jnp.where((rank < cap) & (s != INVALID), rank, -1)
+    # a second key-value sort of (order, inv_sorted) gives the same inv;
+    # the scatter is faster where the plan is vmapped, as the TPU compiler
+    # lowers it to that sort in a flat layout
+    inv = jnp.zeros((m,), jnp.int32).at[order].set(inv_sorted, unique_indices=True)
+    return uniq, inv
 
 
 def unique_compact(ids: jax.Array, cap: int, backend: str = "reference") -> jax.Array:

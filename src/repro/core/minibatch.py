@@ -138,9 +138,10 @@ def build_minibatch(
     """Sample an L-layer minibatch plan (independent path, Fig. 7a).
 
     ``backend`` selects how the frontier hot loop lowers: ``"reference"``
-    is the jnp sort/searchsorted algebra, ``"fused"`` routes dedup + rank
-    resolution through one :func:`repro.core.frontier.unique_with_inverse`
-    sweep (Pallas on TPU).  Outputs are bit-identical.
+    dedups and ranks each hop with one key-value sort
+    (:func:`repro.core.frontier.sort_unique_with_inverse`), ``"fused"``
+    routes dedup + rank resolution through the unique_compact sweep
+    (Pallas on TPU).  Outputs are bit-identical.
     """
     frontier._check_backend(backend)
     with jax.named_scope("plan.seed_draw"):

@@ -75,8 +75,8 @@ class EngineConfig:
     seed: int = 0
     partition_seed: Optional[int] = None  # defaults to ``seed``
     capacity: CapacityPolicy = field(default_factory=CapacityPolicy)
-    # how plan construction lowers: "reference" keeps the jnp
-    # sort/searchsorted frontier algebra; "fused" routes the hot loop
+    # how plan construction lowers: "reference" keeps the jnp frontier
+    # algebra (one key-value sort per dedup); "fused" routes the hot loop
     # through the Pallas kernels (unique_compact / frontier_gather /
     # expand_indptr).  Bit-identical outputs either way.  On the TPU,
     # "fused" raises KernelUnavailableError while one of those kernels

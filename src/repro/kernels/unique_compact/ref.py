@@ -1,9 +1,11 @@
 """Pure-jnp oracle for the fused unique-and-compact frontier op.
 
-Single-pass replacement for the sort-network pair
-``unique_padded(cat, cap)`` + ``lookup(uniq, cat)``: one stable sort of
-the concatenated frontier, first-occurrence flags, cumulative ranks, and
-two scatters.  Bit-identical to the reference pair:
+Single-pass replacement for the pair ``unique_padded(cat, cap)`` +
+``lookup(uniq, cat)``: one key-value sort of the concatenated frontier,
+first-occurrence flags, cumulative ranks, and two scatters — the
+reference plan backend's own
+:func:`repro.core.frontier.sort_unique_with_inverse`.  Bit-identical to
+the reference pair:
 
 * ``uniq`` equals ``jnp.unique(cat, size=cap, fill_value=INVALID)`` —
   INVALID participates as an ordinary value that sorts last, and
@@ -17,33 +19,6 @@ kernel (`repro.kernels.unique_compact.kernel`).
 """
 from __future__ import annotations
 
-from functools import partial
+from repro.core.frontier import sort_unique_with_inverse as unique_with_inverse_ref
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-_INVALID = np.int32(2**31 - 1)  # numpy: safe to create at import time under a trace
-
-
-@partial(jax.jit, static_argnums=(1,))
-def unique_with_inverse_ref(ids: jax.Array, cap: int) -> tuple[jax.Array, jax.Array]:
-    """(uniq (cap,), inv (m,)) for a flat int32 id vector.
-
-    ``uniq``: sorted unique ids, INVALID-padded, smallest ``cap`` kept on
-    overflow.  ``inv``: index of each input in ``uniq``; -1 for INVALID
-    inputs and for uniques dropped by the overflow policy.
-    """
-    flat = ids.reshape(-1)
-    m = flat.shape[0]
-    order = jnp.argsort(flat)
-    s = flat[order]
-    first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
-    rank = (jnp.cumsum(first) - 1).astype(jnp.int32)
-    # rank >= cap parks in slot `cap`, sliced off below; all writers of a
-    # slot < cap carry the same value, so the duplicate scatter is exact
-    slot = jnp.where(rank < cap, rank, cap)
-    uniq = jnp.full((cap + 1,), _INVALID, flat.dtype).at[slot].set(s)[:cap]
-    inv_sorted = jnp.where((rank < cap) & (s != _INVALID), rank, -1)
-    inv = jnp.zeros((m,), jnp.int32).at[order].set(inv_sorted)
-    return uniq, inv
+__all__ = ["unique_with_inverse_ref"]

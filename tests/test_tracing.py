@@ -80,7 +80,8 @@ def test_heavy_ops_carry_a_program_scope(traced, opcode):
     _, hlo, _ = traced
     ops = [ln for ln in hlo.splitlines()
            if (m := OPCODE.search(ln)) and m.group(1) == opcode]
-    assert ops, opcode
+    # the frontier dedup ranks by a sort, not a binary search: no while loop
+    assert bool(ops) == (opcode != "while"), (opcode, ops[:3])
     bare = [ln.strip()[:160] for ln in ops
             if not scopes_of((OP_NAME.search(ln) or [None, ""])[1])]
     assert not bare, bare
