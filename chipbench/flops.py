@@ -2,13 +2,10 @@
 
 Plan layer ``l`` maps the rows of frontier ``S_{l+1}`` to those of
 ``S_l`` over ``E_l`` sampled edges; layer ``L-1`` reads the raw
-features and layer ``0`` emits the logits.  Counted per layer, with
-``n = |S_l|``, ``e = E_l``, ``k`` input and ``m`` output width:
+features and layer ``0`` emits the logits.  Each model's forward count
+of one layer, as an aggregation and a matmul term, is in its module
+(``chipbench/models/<model>.py``).  The rules common to all:
 
-* GCN forward: mean aggregation ``(e + 2n) k`` (sum of neighbours, add
-  self, divide) and the matmul ``2 n k m``;
-* R-GCN forward, ``R`` relations: aggregation ``(e + R n) k`` and
-  ``R + 1`` matmuls, ``2 n k m (R + 1)``;
 * backward: the weight gradient of every matmul (as many FLOPs as its
   forward); the input gradient of the matmuls and of the aggregation
   only where the layer's input is itself trained, i.e. not in layer
@@ -18,24 +15,27 @@ Padding, bias, activation, loss and optimizer FLOPs are not counted.
 """
 from __future__ import annotations
 
+import byname
 
-def step_flops(model: str, sizes: list, edges: list, in_dim: int,
-               hidden: int, classes: int, num_relations: int = 1) -> float:
+
+def step_flops(cfg: dict, sizes: list, edges: list) -> float:
     """FLOPs of forward + backward for frontier sizes ``sizes`` (S_0 ..
-    S_L) and sampled edge counts ``edges`` (E_0 .. E_{L-1})."""
+    S_L) and sampled edge counts ``edges`` (E_0 .. E_{L-1}) of the
+    configuration ``cfg``'s model."""
+    return byname.model(cfg["model"]).step_flops(sizes, edges, cfg)
+
+
+def train_step_flops(sizes: list, edges: list, cfg: dict, layer_counts) -> float:
+    """The rules above over every layer; ``layer_counts(n, e, k, m)``
+    gives one layer's forward ``(aggregation, matmul)`` FLOPs for
+    ``n = |S_l|``, ``e = E_l``, ``k`` input and ``m`` output width."""
     L = len(edges)
     total = 0.0
     for l in range(L):
         n, e = float(sizes[l]), float(edges[l])
-        k = in_dim if l == L - 1 else hidden
-        m = classes if l == 0 else hidden
-        if model == "gcn":
-            agg, mm = (e + 2 * n) * k, 2 * n * k * m
-        elif model == "rgcn":
-            agg = (e + num_relations * n) * k
-            mm = 2 * n * k * m * (num_relations + 1)
-        else:
-            raise ValueError(f"no FLOP count for model {model!r}")
+        k = cfg["feature_dim"] if l == L - 1 else cfg["hidden_dim"]
+        m = cfg["num_classes"] if l == 0 else cfg["hidden_dim"]
+        agg, mm = layer_counts(n, e, k, m)
         trained_input = l < L - 1
         total += agg + mm              # forward
         total += mm                    # weight gradients

@@ -11,11 +11,6 @@ def read(ctx):
         return None
     cfg = ctx["config"]
     L = cfg["num_layers"]
-    R = len(cfg["graph"].get("relation_shares", [1.0]))
-    total = sum(
-        flops.step_flops(cfg["model"], row[: L + 1], row[L + 1:],
-                         cfg["feature_dim"], cfg["hidden_dim"],
-                         cfg["num_classes"], R)
-        for row in counts)
+    total = sum(flops.step_flops(cfg, row[: L + 1], row[L + 1:]) for row in counts)
     peak = ctx["peaks"]["flops_bf16"] * ctx["chips"]
     return 100.0 * total / (ctx["window_s"] * peak)

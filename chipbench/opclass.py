@@ -10,7 +10,7 @@ its own and those of every computation it calls, and one category:
 * ``collective``: all-to-all, all-reduce, all-gather, reduce-scatter,
   collective-permute (and their async start/done halves);
 * ``sort``: a sort;
-* ``loop``: a while loop (the frontier lookups' binary searches);
+* ``loop``: a while loop;
 * ``matmul``: holds a dot or a convolution;
 * ``gather``: holds a gather, scatter, dynamic-slice or
   dynamic-update-slice;
@@ -89,13 +89,11 @@ class Classifier:
         return "other"
 
 
-def ms_per_step(ctx, category, none_if_zero=False):
+def ms_per_step(ctx, category):
     """Device milliseconds per step, per chip, of one category."""
     red, hlo = ctx.get("trace"), ctx.get("hlo")
     if red is None or hlo is None:
         return None
     cls = ctx.setdefault("classifier", Classifier(hlo))
     s = red.category_s(lambda op: cls.category(op.name) == category)
-    if none_if_zero and s == 0:
-        return None
     return 1e3 * s / ctx["trace_steps"]

@@ -8,9 +8,9 @@ from scratch and importing nothing of the program:
 * sampling: LABOR-0 (Balin & Catalyurek 2023) with one uniform
   ``r_t`` per source vertex and layer, an edge ``t -> s`` kept iff
   ``r_t <= min(1, fanout / deg(s))``; frontiers are exact vertex sets;
-* layers: GCN is the mean over ``{s} + sampled N(s)`` followed by
-  ``x W + b``; R-GCN is ``h_s W_self + sum_r mean_r(N_r(s)) W_r + b``;
-  ReLU on all but the output layer;
+* layers: each model's layer, activation included, and its parameters
+  are in its own module, ``chipbench/models/<model>.py`` (``byname.py``
+  says what one holds); this file keeps what every model shares;
 * loss: mean softmax cross entropy over the seeds;
 * update: Adam (lr 1e-3, betas 0.9 / 0.999, eps 1e-8).
 
@@ -23,11 +23,13 @@ sampled edges are the ones the definition prescribes for this seed.
 
 ``variant`` selects what stands in the program's place for the
 limits: ``"f32"`` (the reference), ``"bf16"`` (the same steps computed
-in bfloat16, the control) and ``"half"`` (the loss averaged over half
-the seeds, a planted fault).
+in bfloat16, read beside the control, which is the program's own
+bfloat16 path) and ``"half"`` (the loss averaged over half the seeds, a
+planted fault).
 """
 from __future__ import annotations
 
+import json
 from dataclasses import dataclass
 from functools import partial
 
@@ -35,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.scipy.stats import norm
+
+import byname
 
 INVALID = np.int32(np.iinfo(np.int32).max)
 GOLDEN = np.uint32(0x9E3779B9)
@@ -209,40 +213,20 @@ def pad_plan(frontiers, layers, labels):
     return arrs
 
 
-def _layer(p, model, h, L, is_out, dtype, prec, num_relations):
-    n = L["self_idx"].shape[0]
-    w = L["w"].astype(dtype)
-    h_self = h[L["self_idx"]]
-    msg = h[L["src"]] * w[:, None]
-    if model == "gcn":
-        cnt = jax.ops.segment_sum(w, L["dst"], n)
-        agg = (jax.ops.segment_sum(msg, L["dst"], n) + h_self) / (cnt + 1)[:, None]
-        out = jnp.matmul(agg, p["w"], precision=prec) + p["b"]
-    elif model == "rgcn":
-        out = jnp.matmul(h_self, p["w_self"], precision=prec)
-        for r in range(num_relations):
-            wr = jnp.where(L["etype"] == r, w, 0)
-            cnt = jax.ops.segment_sum(wr, L["dst"], n)
-            s = jax.ops.segment_sum(msg * (L["etype"] == r)[:, None].astype(dtype),
-                                    L["dst"], n)
-            out = out + jnp.matmul(s / jnp.maximum(cnt, 1)[:, None], p["w_rel"][r],
-                                   precision=prec)
-        out = out + p["b"]
-    else:
-        raise ValueError(f"no reference for model {model!r}")
-    return out if is_out else jax.nn.relu(out)
-
-
-@partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def loss_and_grad(params, features, arrs, model, num_relations, variant, num_layers):
+@partial(jax.jit, static_argnums=(3, 4))
+def loss_and_grad(params, features, arrs, config, variant):
+    """Loss and gradients of one step over the padded plan ``arrs``;
+    ``config`` is the configuration as JSON text (static, so it hashes)."""
+    cfg = json.loads(config)
+    model = byname.model(cfg["model"])
     dtype = jnp.bfloat16 if variant == "bf16" else jnp.float32
     prec = jax.lax.Precision.DEFAULT if variant == "bf16" else HIGHEST
 
     def loss_fn(params):
         h = features[arrs["input_ids"]].astype(dtype) * arrs["input_ok"][:, None].astype(dtype)
-        for l in reversed(range(num_layers)):
-            h = _layer(params["layers"][l], model, h, arrs["layers"][l], l == 0,
-                       dtype, prec, num_relations)
+        for l in reversed(range(cfg["num_layers"])):
+            h = model.layer(params["layers"][l], h, arrs["layers"][l], l == 0,
+                            dtype, prec, cfg)
         logits = h.astype(jnp.float32)
         ce = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
             logits, arrs["labels"][:, None], -1)[:, 0]
@@ -258,27 +242,24 @@ def loss_and_grad(params, features, arrs, model, num_relations, variant, num_lay
 # --------------------------------------------------------------------------
 # Parameters and Adam
 # --------------------------------------------------------------------------
-def init_params(seed: int, model: str, num_layers: int, in_dim: int,
-                hidden: int, classes: int, num_relations: int) -> dict:
-    """Glorot-uniform weights and zero biases from ``PRNGKey(seed)``: five
-    subkeys per layer, layer 0 (the output layer) first."""
-    def glorot(k, shape):
-        lim = float(np.sqrt(6.0 / (shape[-2] + shape[-1])))
-        return jax.random.uniform(k, shape, jnp.float32, -lim, lim)
+def glorot(k, shape):
+    lim = float(np.sqrt(6.0 / (shape[-2] + shape[-1])))
+    return jax.random.uniform(k, shape, jnp.float32, -lim, lim)
 
+
+def init_params(seed: int, cfg: dict) -> dict:
+    """The model's layers (``init_layer``), each from five subkeys of
+    ``PRNGKey(seed)``, layer 0 (the output layer) first: Glorot-uniform
+    weights and zero biases."""
+    model = byname.model(cfg["model"])
+    L = cfg["num_layers"]
     key = jax.random.PRNGKey(seed)
     layers = []
-    for l in range(num_layers):
-        d_in = in_dim if l == num_layers - 1 else hidden
-        d_out = classes if l == 0 else hidden
+    for l in range(L):
+        d_in = cfg["feature_dim"] if l == L - 1 else cfg["hidden_dim"]
+        d_out = cfg["num_classes"] if l == 0 else cfg["hidden_dim"]
         key, *ks = jax.random.split(key, 6)
-        if model == "gcn":
-            layers.append({"w": glorot(ks[0], (d_in, d_out)),
-                           "b": jnp.zeros((d_out,), jnp.float32)})
-        else:
-            layers.append({"w_self": glorot(ks[0], (d_in, d_out)),
-                           "w_rel": glorot(ks[1], (num_relations, d_in, d_out)),
-                           "b": jnp.zeros((d_out,), jnp.float32)})
+        layers.append(model.init_layer(ks, d_in, d_out, cfg))
     return {"layers": layers}
 
 
@@ -299,25 +280,25 @@ def adam(params, grads, mu, nu, t):
 # --------------------------------------------------------------------------
 # The steps
 # --------------------------------------------------------------------------
-def run(g: HostGraph, features, labels, train_ids, *, seed, model, num_layers,
-        in_dim, hidden, classes, num_relations, fanout, mode, num_pes,
-        local_batch, steps=3, variant="f32"):
-    """Losses of the first ``steps`` steps, the first gradient, the
-    parameters before step 0 and after ``steps`` updates (host numpy)."""
-    params = init_params(seed, model, num_layers, in_dim, hidden, classes,
-                         num_relations)
+def run(g: HostGraph, features, labels, train_ids, *, seed, cfg, mode,
+        num_pes, steps=3, variant="f32"):
+    """Losses of the first ``steps`` steps of the configuration ``cfg``,
+    the first gradient, the parameters before step 0 and after ``steps``
+    updates (host numpy)."""
+    params = init_params(seed, cfg)
     p0 = jax.tree.map(np.asarray, params)
     if variant == "bf16":
         params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
+    config = json.dumps(cfg, sort_keys=True)
     mu = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     nu = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     losses, g1 = [], None
     for step in range(steps):
-        seeds = seed_rows(train_ids, seed, step, mode, num_pes, local_batch)
-        frontiers, layers = build_plan(g, seeds, seed, step, fanout, num_layers)
+        seeds = seed_rows(train_ids, seed, step, mode, num_pes, cfg["local_batch"])
+        frontiers, layers = build_plan(g, seeds, seed, step, cfg["fanout"],
+                                       cfg["num_layers"])
         arrs = pad_plan(frontiers, layers, labels)
-        loss, grads = loss_and_grad(params, features, arrs, model, num_relations,
-                                    variant, num_layers)
+        loss, grads = loss_and_grad(params, features, arrs, config, variant)
         losses.append(float(loss))
         if g1 is None:
             g1 = jax.tree.map(lambda x: np.asarray(x, np.float32), grads)

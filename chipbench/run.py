@@ -7,7 +7,8 @@ Everything a cell is made of is found by name: the cell in
 its traffic (``chipbench/traffic/<traffic>.json``) and has its
 correctness limits in ``chipbench/cells/<cell>.json``; each metric is
 read by ``chipbench/metrics/<metric>.py``, a module with
-``read(ctx) -> float | None``.
+``read(ctx) -> float | None``; the configuration's model is
+``chipbench/models/<model>.py`` (``byname.py``).
 
 A run generates the dataset on the device from ``--seed``, makes one
 warm-up call of the training loop ``repro.train.loop.train_gnn``
@@ -36,13 +37,14 @@ T0 = time.perf_counter()
 import argparse  # noqa: E402
 import gc  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+
+import byname  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -87,14 +89,13 @@ def load_spec(root: str, workload: str) -> dict:
 
 def read_metric(metrics_dir: str, name: str, ctx: dict):
     path = os.path.join(metrics_dir, name + ".py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    label = "chipbench_metric_" + name.replace(".", "_").replace("-", "_")
+    return byname.load(path, label).read(ctx)
 
 
 def gnn_config(cfg: dict):
+    """The program's model: the fields every model has, and those its
+    module (``chipbench/models/<model>.py``) adds."""
     import jax.numpy as jnp
 
     from repro.models.gnn import GNNConfig
@@ -102,9 +103,8 @@ def gnn_config(cfg: dict):
     return GNNConfig(
         model=cfg["model"], num_layers=cfg["num_layers"],
         in_dim=cfg["feature_dim"], hidden_dim=cfg["hidden_dim"],
-        num_classes=cfg["num_classes"],
-        num_relations=len(cfg["graph"].get("relation_shares", [1.0])),
-        dtype=jnp.dtype(cfg["dtype"]),
+        num_classes=cfg["num_classes"], dtype=jnp.dtype(cfg["dtype"]),
+        **byname.model(cfg["model"]).program_args(cfg),
     )
 
 
@@ -188,11 +188,8 @@ def reference_state(jax, spec: dict, ds, variant: str = "f32") -> dict:
         num_vertices=g.num_vertices, max_degree=g.max_degree)
     return reference.run(
         hg, ds.features, np.asarray(ds.labels), ds.train_ids,
-        seed=PROGRAM_SEED, model=cfg["model"], num_layers=cfg["num_layers"],
-        in_dim=cfg["feature_dim"], hidden=cfg["hidden_dim"],
-        classes=cfg["num_classes"], num_relations=g.num_edge_types,
-        fanout=cfg["fanout"], mode=tr["mode"], num_pes=tr["num_pes"],
-        local_batch=cfg["local_batch"], steps=3, variant=variant)
+        seed=PROGRAM_SEED, cfg=cfg, mode=tr["mode"], num_pes=tr["num_pes"],
+        steps=3, variant=variant)
 
 
 def plan_counts(jax, ds, gnn_cfg, tc, steps: int):

@@ -61,6 +61,9 @@ def test_every_piece_is_a_file_of_its_own():
     assert len(files) == len(set(files))
     for f in files:
         assert os.path.isfile(os.path.join(ROOT, f)) and f.startswith("chipbench/")
+        with open(os.path.join(ROOT, f)) as fh:
+            model = json.load(fh)["model"]
+        assert os.path.isfile(os.path.join(BENCH, "models", model + ".py")), model
 
 
 def test_a_cell_dropped_into_a_copy_is_found(tmp_path):

@@ -1,4 +1,5 @@
-"""The FLOP count of ``step.mfu.train`` by hand at a tiny plan."""
+"""The FLOP count of ``step.mfu.train`` by hand at a tiny plan, through
+each model's module."""
 import flops
 
 
@@ -9,7 +10,8 @@ def test_gcn_two_layers_by_hand():
     # Layer 0 (4 -> 2): forward (4 + 2*2)*4 + 2*2*4*2 = 32 + 32, weight
     # grad 32, input grad 32 + 32.
     want = (63 + 120 + 120) + (32 + 32 + 32 + 32 + 32)
-    assert flops.step_flops("gcn", [2, 5, 9], [4, 11], 3, 4, 2) == want
+    cfg = {"model": "gcn", "feature_dim": 3, "hidden_dim": 4, "num_classes": 2}
+    assert flops.step_flops(cfg, [2, 5, 9], [4, 11]) == want
 
 
 def test_rgcn_counts_a_matmul_per_relation_and_self():
@@ -17,4 +19,6 @@ def test_rgcn_counts_a_matmul_per_relation_and_self():
     # R + 1 matmuls forward and as many weight-gradient matmuls
     n, e, k, m, R = 3, 7, 5, 2, 2
     want = (e + R * n) * k + 2 * (2 * n * k * m * (R + 1))
-    assert flops.step_flops("rgcn", [n, 10], [e], k, 8, m, R) == want
+    cfg = {"model": "rgcn", "feature_dim": k, "hidden_dim": 8, "num_classes": m,
+           "graph": {"relation_shares": [0.5, 0.5]}}
+    assert flops.step_flops(cfg, [n, 10], [e]) == want

@@ -19,7 +19,7 @@ READERS = ["plan.seed_draw_ms.train", "plan.hops_ms.train",
            "plan.last_hop_ms.train", "fetch.inputs_ms.train",
            "gnn.forward_ms.train", "gnn.backward_ms.train",
            "optim.update_ms.train", "host.step_gap_ms.train",
-           "trace.unscoped_share.train"]
+           "trace.unscoped_share.train", "exchange.ms.train"]
 PARTS = {"plan.seed_draw": "plan.seed_draw_ms.train",
          "plan.hops": "plan.hops_ms.train",
          "fetch.inputs": "fetch.inputs_ms.train",
@@ -54,6 +54,8 @@ def test_every_reader_reads_the_scoped_step(scoped_step):
     assert all(isinstance(v, float) and v >= 0 for v in got.values()), got
     assert got["plan.last_hop_ms.train"] <= got["plan.hops_ms.train"]
     assert got["plan.seed_draw_ms.train"] > 0 and got["gnn.backward_ms.train"] > 0
+    # one PE: nothing is exchanged
+    assert got["exchange.ms.train"] == 0
 
 
 def test_parts_sum_to_the_step(scoped_step):
@@ -67,8 +69,9 @@ def test_parts_sum_to_the_step(scoped_step):
 
 
 def test_the_last_hop_holds_the_searches(scoped_step):
-    """The frontier lookups' while loops (``plan.search_ms.train``) are
-    almost all in hop 3, as PERF.md read from the shapes by hand."""
+    """The frontier lookups' while loops (binary searches until the
+    program's dedup became one sort) are almost all in hop 3, as read
+    from the shapes by hand."""
     ctx = scoped_step
     sc = scopes.Scopes(ctx["hlo"])
     cls = opclass.Classifier(ctx["hlo"])
@@ -145,3 +148,21 @@ def test_step_gap_counts_gaps_under_step_spans():
     assert scopes.step_gap_ms(ctx) == pytest.approx((4 + 10) / 2 / 1e6)
     ctx = {"trace": tr.Reduced(devices=[dev], host=[ev("other", 0, 300)])}
     assert scopes.step_gap_ms(ctx) is None
+
+
+def test_exchange_counts_every_exchange_scope():
+    """``exchange.ms.train``: ops under any ``exchange.*`` scope, wherever
+    it sits in the path (inside a hop or a layer, or alone)."""
+    names = {"a2a.1": "jit(train_step)/jvp(jit(build))/plan.hop2/exchange.ids/all_to_all",
+             "a2a.2": "jit(train_step)/transpose(jvp(gnn.layer1))/exchange.embeddings/all_to_all",
+             "ar.3": "jit(train_step)/exchange.grads/psum",
+             "fusion.4": "jit(train_step)/jvp(gnn.layer1)/dot_general"}
+    hlo = "\n".join(["ENTRY %main (a: f32[4]) -> f32[4] {"] + [
+        f'  %{n} = f32[4]{{0}} add(%a, %a), metadata={{op_name="{o}"}}'
+        for n, o in names.items()] + ["}"])
+    ops = [tr.Event(f"%{n} = f32[4]{{0}} add()", 10 * i, 5 * (i + 1))
+           for i, n in enumerate(names)]
+    dev = tr.Device(index=0, modules=[], ops=ops, busy=[], start_ns=0, end_ns=40)
+    ctx = {"trace": tr.Reduced(devices=[dev], host=[]), "hlo": hlo, "trace_steps": 2}
+    got = run.read_metric(METRICS, "exchange.ms.train", ctx)
+    assert got == pytest.approx((5 + 10 + 15) / 2 / 1e6)
