@@ -58,14 +58,21 @@ def init_gnn(key: jax.Array, cfg: GNNConfig) -> dict:
                 "b": jnp.zeros((d_out,), cfg.dtype),
             }
         elif cfg.model == "gat":
+            # GATConv: lin (no bias), att_src / att_dst, bias; plus the
+            # linear skip.  Hidden layers concatenate H heads of C = d_out
+            # / H, the output layer averages H heads of C = d_out.
             h = cfg.num_heads
-            dh = max(1, d_out // h)
+            if l > 0 and d_out % h:
+                raise ValueError(f"gat: hidden_dim {d_out} is not a multiple "
+                                 f"of num_heads {h}")
+            c = d_out if l == 0 else d_out // h
             p = {
-                "w": _glorot(ks[0], (d_in, h * dh), cfg.dtype),
-                "a_src": _glorot(ks[1], (h, dh, 1), cfg.dtype)[..., 0],
-                "a_dst": _glorot(ks[2], (h, dh, 1), cfg.dtype)[..., 0],
-                "w_out": _glorot(ks[3], (h * dh, d_out), cfg.dtype),
-                "b": jnp.zeros((d_out,), cfg.dtype),
+                "w": _glorot(ks[0], (d_in, h * c), cfg.dtype),
+                "att_src": _glorot(ks[1], (h, c), cfg.dtype),
+                "att_dst": _glorot(ks[2], (h, c), cfg.dtype),
+                "b": jnp.zeros((c if l == 0 else h * c,), cfg.dtype),
+                "w_skip": _glorot(ks[3], (d_in, d_out), cfg.dtype),
+                "b_skip": jnp.zeros((d_out,), cfg.dtype),
             }
         elif cfg.model == "rgcn":
             p = {
@@ -102,6 +109,8 @@ def layer_apply(
     etypes,
 ) -> jax.Array:
     """One bipartite GNN layer: (cap_tilde, d_in) -> (cap_l, d_out)."""
+    if cfg.model == "gat":
+        return _gat_layer(p, l, Ht, self_idx, nbr_idx, mask)
     # plan layer 0 emits logits (no activation); deeper layers use ReLU
     act = (lambda x: x) if l == 0 else jax.nn.relu
     h_self = _gather(Ht, self_idx)              # (n, d_in)
@@ -114,20 +123,6 @@ def layer_apply(
     if cfg.model == "sage":
         agg = _masked_mean(h_nbr, mask)
         return act(h_self @ p["w_self"] + agg @ p["w_nbr"] + p["b"])
-    if cfg.model == "gat":
-        h = cfg.num_heads
-        z_self = (h_self @ p["w"]).reshape(*h_self.shape[:-1], h, -1)   # (n,h,dh)
-        z_nbr = (h_nbr @ p["w"]).reshape(*h_nbr.shape[:-1], h, -1)     # (n,w,h,dh)
-        e_dst = jnp.einsum("nhd,hd->nh", z_self, p["a_dst"])           # (n,h)
-        e_src = jnp.einsum("nwhd,hd->nwh", z_nbr, p["a_src"])          # (n,w,h)
-        e = jax.nn.leaky_relu(e_src + e_dst[:, None, :], 0.2)
-        e = jnp.where(mask[..., None], e, -1e9)
-        alpha = jax.nn.softmax(e, axis=1)
-        alpha = jnp.where(mask[..., None], alpha, 0.0)
-        agg = jnp.einsum("nwh,nwhd->nhd", alpha, z_nbr)
-        agg = agg.reshape(*agg.shape[:-2], -1)                          # (n, h*dh)
-        self_part = z_self.reshape(*z_self.shape[:-2], -1)
-        return act((agg + self_part) @ p["w_out"] + p["b"])
     if cfg.model == "rgcn":
         out = h_self @ p["w_self"]
         et = etypes if etypes is not None else jnp.zeros(mask.shape, jnp.int32)
@@ -137,6 +132,44 @@ def layer_apply(
             out = out + agg_r @ p["w_rel"][r]
         return act(out + p["b"])
     raise ValueError(cfg.model)
+
+
+def _gat_layer(p, l, Ht, self_idx, nbr_idx, mask):
+    """GAT (Veličković et al., ICLR 2018) as PyG's ``GATConv`` with a
+    linear skip, over the slots ``{i} ∪ sampled N(i)`` of each destination:
+
+        z_j = W x_j
+        α^h = softmax_j LeakyReLU_0.2(att_src^h · z_j^h + att_dst^h · z_i^h)
+        o_i = concat_h Σ_j α^h_ij z_j^h + b   (layer 0: mean_h)
+        out = ELU(o_i + W_skip x_i + b_skip)  (layer 0: no ELU)
+
+    No gathered copy is ever projected.  The scores are scalars per row,
+    ``att · (W x) = x · (W att)``, computed once per row and then gathered
+    to the slots.  The raw rows are reduced per head with α to (n, H, k),
+    and each head is then projected with its (k, C) slice of W.
+    """
+    k = Ht.shape[-1]
+    H, C = p["att_src"].shape
+    w3 = p["w"].reshape(k, H, C)
+    h_self = _gather(Ht, self_idx)                                # (n, k)
+    with jax.named_scope("gnn.attn.score"):
+        s_src = Ht @ jnp.einsum("khc,hc->kh", w3, p["att_src"])   # (n_src, H)
+        s_dst = h_self @ jnp.einsum("khc,hc->kh", w3, p["att_dst"])
+        e_self = jax.nn.leaky_relu(_gather(s_src, self_idx) + s_dst, 0.2)
+        e_nbr = jax.nn.leaky_relu(_gather(s_src, nbr_idx) + s_dst[:, None], 0.2)
+        e_nbr = jnp.where(mask[..., None], e_nbr, -jnp.inf)      # (n, w, H)
+        top = jax.lax.stop_gradient(jnp.maximum(e_self, jnp.max(e_nbr, axis=1)))
+        x_self = jnp.exp(e_self - top)
+        x_nbr = jnp.exp(e_nbr - top[:, None])
+        den = x_self + jnp.sum(x_nbr, axis=1)
+        a_self, a_nbr = x_self / den, x_nbr / den[:, None]
+    with jax.named_scope("gnn.attn.aggregate"):
+        agg = (a_self[..., None] * h_self[:, None]
+               + jnp.einsum("nwh,nwk->nhk", a_nbr, _gather(Ht, nbr_idx)))
+    out = jnp.einsum("nhk,khc->nhc", agg, w3)                    # (n, H, C)
+    out = out.mean(axis=1) if l == 0 else out.reshape(-1, H * C)
+    out = out + p["b"] + h_self @ p["w_skip"] + p["b_skip"]
+    return out if l == 0 else jax.nn.elu(out)
 
 
 def gnn_apply(

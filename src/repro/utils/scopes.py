@@ -11,9 +11,11 @@ from __future__ import annotations
 import re
 
 # plan.seed_draw, plan.hop1..plan.hop<L>, fetch.inputs,
-# gnn.layer0..gnn.layer<L-1>, gnn.loss, optim.update and, around the
-# collectives alone, exchange.ids / exchange.embeddings / exchange.grads
-SCOPE = re.compile(r"^(?:plan\.(?:seed_draw|hop\d+)|fetch\.inputs|gnn\.(?:layer\d+|loss)"
+# gnn.layer0..gnn.layer<L-1> (GAT's gnn.attn.score and gnn.attn.aggregate
+# inside them), gnn.loss, optim.update and, around the collectives alone,
+# exchange.ids / exchange.embeddings / exchange.grads
+SCOPE = re.compile(r"^(?:plan\.(?:seed_draw|hop\d+)|fetch\.inputs"
+                   r"|gnn\.(?:layer\d+|loss|attn\.(?:score|aggregate))"
                    r"|optim\.update|exchange\.(?:ids|embeddings|grads))$")
 _WRAPPED = re.compile(r"^[\w.\-]+\((.*)\)$")
 

@@ -111,6 +111,11 @@ def test_step_seconds_and_one_trace(traced):
     ("jit(train_step)/jvp(jit(build))/plan.hop3/exchange.ids/all_to_all",
      ["plan.hop3", "exchange.ids"]),
     ("jit(train_step)/jvp(jit(build))/vmap(jit(_neighbor_table))/gather", []),
+    ("jit(train_step)/transpose(jvp(gnn.layer1))/vmap(gnn.attn.score)/exp",
+     ["gnn.layer1", "gnn.attn.score"]),
+    ("jit(train_step)/jvp(gnn.layer2)/vmap(gnn.attn.aggregate)/nwh,nwk->nhk/dot_general",
+     ["gnn.layer2", "gnn.attn.aggregate"]),
+    ("jit(train_step)/jvp(gnn.layer0)/gnn.attn/gnn.attn.scores", ["gnn.layer0"]),
     ("jit(train_step)/plan.hop/optim.update.x/gnn.layer", []),
 ])
 def test_scopes_of_unwraps_transforms(op_name, want):
