@@ -134,6 +134,31 @@ def layer_apply(
     raise ValueError(cfg.model)
 
 
+@jax.custom_vjp
+def _slot_scores(h_nbr, a):
+    """Each slot's scores from its gathered row: (n, w, k) · (k, H) -> (n, w, H)."""
+    return jnp.einsum("nwk,kh->nwh", h_nbr, a)
+
+
+def _slot_scores_fwd(h_nbr, a):
+    return _slot_scores(h_nbr, a), (h_nbr, a)
+
+
+def _slot_scores_bwd(res, g):
+    # a's gradient as one contraction per destination, then a sum over
+    # destinations.  Left to autodiff it is one contraction over every
+    # slot, for which XLA on the TPU copies h_nbr into a transposed layout
+    # (GAT on ogbn-products, layer 2 on a v5e: 4.4 ms a step and 1.4 GB
+    # more step memory); the barrier keeps XLA from merging the two back.
+    h_nbr, a = res
+    g_a = jax.lax.optimization_barrier(jnp.einsum("nwh,nwk->nhk", g, h_nbr))
+    return (jnp.einsum("nwh,kh->nwk", g, a).astype(h_nbr.dtype),
+            g_a.sum(0).T.astype(a.dtype))
+
+
+_slot_scores.defvjp(_slot_scores_fwd, _slot_scores_bwd)
+
+
 def _gat_layer(p, l, Ht, self_idx, nbr_idx, mask):
     """GAT (Veličković et al., ICLR 2018) as PyG's ``GATConv`` with a
     linear skip, over the slots ``{i} ∪ sampled N(i)`` of each destination:
@@ -143,20 +168,25 @@ def _gat_layer(p, l, Ht, self_idx, nbr_idx, mask):
         o_i = concat_h Σ_j α^h_ij z_j^h + b   (layer 0: mean_h)
         out = ELU(o_i + W_skip x_i + b_skip)  (layer 0: no ELU)
 
-    No gathered copy is ever projected.  The scores are scalars per row,
-    ``att · (W x) = x · (W att)``, computed once per row and then gathered
-    to the slots.  The raw rows are reduced per head with α to (n, H, k),
-    and each head is then projected with its (k, C) slice of W.
+    No gathered copy is ever projected.  The scores are ``att · (W x) =
+    x · (W att)``, taken per slot from the rows the layer gathers anyway
+    for the reduce (the self slot's from ``h_self``).  Scores computed per
+    row and then gathered would put a scatter-add of every slot's score
+    gradient into the backward pass; per slot, ``W att``'s gradient is a
+    dense contraction over the slots (``_slot_scores``).  The raw rows are
+    reduced per head with α to (n, H, k), and each head is then projected
+    with its (k, C) slice of W.
     """
     k = Ht.shape[-1]
     H, C = p["att_src"].shape
     w3 = p["w"].reshape(k, H, C)
     h_self = _gather(Ht, self_idx)                                # (n, k)
     with jax.named_scope("gnn.attn.score"):
-        s_src = Ht @ jnp.einsum("khc,hc->kh", w3, p["att_src"])   # (n_src, H)
+        h_nbr = _gather(Ht, nbr_idx)                              # (n, w, k)
+        a_src = jnp.einsum("khc,hc->kh", w3, p["att_src"])
         s_dst = h_self @ jnp.einsum("khc,hc->kh", w3, p["att_dst"])
-        e_self = jax.nn.leaky_relu(_gather(s_src, self_idx) + s_dst, 0.2)
-        e_nbr = jax.nn.leaky_relu(_gather(s_src, nbr_idx) + s_dst[:, None], 0.2)
+        e_self = jax.nn.leaky_relu(h_self @ a_src + s_dst, 0.2)
+        e_nbr = jax.nn.leaky_relu(_slot_scores(h_nbr, a_src) + s_dst[:, None], 0.2)
         e_nbr = jnp.where(mask[..., None], e_nbr, -jnp.inf)      # (n, w, H)
         top = jax.lax.stop_gradient(jnp.maximum(e_self, jnp.max(e_nbr, axis=1)))
         x_self = jnp.exp(e_self - top)
@@ -165,7 +195,7 @@ def _gat_layer(p, l, Ht, self_idx, nbr_idx, mask):
         a_self, a_nbr = x_self / den, x_nbr / den[:, None]
     with jax.named_scope("gnn.attn.aggregate"):
         agg = (a_self[..., None] * h_self[:, None]
-               + jnp.einsum("nwh,nwk->nhk", a_nbr, _gather(Ht, nbr_idx)))
+               + jnp.einsum("nwh,nwk->nhk", a_nbr, h_nbr))
     out = jnp.einsum("nhk,khc->nhc", agg, w3)                    # (n, H, C)
     out = out.mean(axis=1) if l == 0 else out.reshape(-1, H * C)
     out = out + p["b"] + h_self @ p["w_skip"] + p["b_skip"]

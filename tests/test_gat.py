@@ -2,8 +2,9 @@
 (``repro.models.gnn.gat_ref``), on seeded random weights at a small size.
 
 Tolerance: ``TOL``, relative to the largest entry of the reference.  The
-program reorders the same float32 arithmetic (scores as ``x · (W att)``,
-the reduce before the projection), which moves
+program reorders the same float32 arithmetic (each slot's scores as
+``x_j · (W att)`` from the rows it gathers, the reduce before the
+projection), which moves
 results by about 1e-7 of their scale (1e-6 for gradients through three
 layers); the same layer computed in bfloat16 moves them by about 5e-3,
 which ``test_bfloat16_fails_the_tolerance`` holds to.
@@ -122,6 +123,49 @@ def test_bfloat16_fails_the_tolerance(l, k):
     out = layer_apply(pb, cfg, l, x.astype(jnp.bfloat16), self_idx, nbr, mask, None)
     assert rel_err(out.astype(jnp.float32),
                    reference_layer(p, l, x, self_idx, nbr, mask)) > 20 * TOL
+
+
+def scatter_add_operands(closed_jaxpr) -> list[tuple[int, ...]]:
+    """Shapes of the operands of every ``scatter-add`` in a jaxpr, nested
+    calls included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scatter-add":
+                found.append(tuple(eqn.invars[0].aval.shape))
+            for v in eqn.params.values():
+                if hasattr(v, "eqns"):
+                    walk(v)
+                elif hasattr(getattr(v, "jaxpr", None), "eqns"):
+                    walk(v.jaxpr)
+
+    walk(closed_jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("l,x_differentiated", [(1, False), (1, True), (0, True)],
+                         ids=["input-layer", "hidden-layer", "output-layer"])
+def test_score_gradient_needs_no_scatter(l, x_differentiated):
+    """The backward pass scatters no score gradient to the source rows: each
+    slot's source score comes from the row it gathers for the reduce, so
+    only the row gather's own scatter-add into ``(n_src, k)`` is left, and
+    none where ``x`` is the undifferentiated input."""
+    # plan layer 1 of ``layer_case`` reads the input features; with ``x``
+    # differentiated it is a hidden layer of a deeper model
+    cfg, p, x, self_idx, nbr, mask = layer_case(l, 8 if l else 16)
+    H = p["att_src"].shape[0]
+
+    def f(p, x):
+        return jnp.sum(_layer_apply(p, cfg, l, x, self_idx, nbr, mask, None))
+
+    argnums = (0, 1) if x_differentiated else 0
+    found = scatter_add_operands(jax.make_jaxpr(jax.grad(f, argnums))(p, x))
+    assert (x.shape[0], H) not in found
+    if x_differentiated:
+        assert found and set(found) == {x.shape}
+    else:
+        assert found == []
 
 
 # --------------------------------------------------------------------------
