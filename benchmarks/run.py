@@ -17,7 +17,6 @@ Sections:
     serve          coalescing inference server vs per-request baseline
     convergence    Fig 4/9  (coop vs indep; kappa parity)
     kernels        per-kernel shape sweep
-    roofline       §Roofline summary from experiments/dryrun/*.json
 
 Every section persists a machine-readable ``BENCH_<section>.json``
 snapshot (see docs/benchmarks.md for the snapshot/gate workflow).
@@ -99,7 +98,6 @@ def main() -> None:
         bench_kernels,
         bench_monotonicity,
         bench_plan_build,
-        bench_roofline,
         bench_serve,
     )
 
@@ -114,7 +112,6 @@ def main() -> None:
     register("serve", lambda: bench_serve.run(fast=args.fast))
     register("convergence", bench_convergence.run)
     register("kernels", bench_kernels.run)
-    register("roofline", bench_roofline.run)
 
     todo = [args.only] if args.only else list(sections)
     for name in todo:

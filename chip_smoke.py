@@ -34,7 +34,7 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-# papers100M / GCN widths (paper Table 2 and A.5; launch/gnn_dryrun.py)
+# papers100M / GCN widths (paper Table 2 and A.5)
 FEAT, HIDDEN, CLASSES, LAYERS = 128, 1024, 172, 3
 FANOUT, DEG_CAP, BATCH = 10, 32, 1024
 # Graph500's RMAT skew (a = 0.57) leaves the mean degree under 17 after

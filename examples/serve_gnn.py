@@ -2,14 +2,13 @@
 
     PYTHONPATH=src python examples/serve_gnn.py [--smoke]
 
-The graph-side sibling of ``serve_lm.py``: a synthetic user-item
-recommendation graph (power-law degrees on both sides) takes a Poisson
-stream of user ego-network queries; the server coalesces concurrent
-requests into ONE shared minibatch plan per dispatch (the paper's
-concavity argument applied to inference), gathers features through the
-warm device cache, and scatters per-request predictions back out with
-latency accounting.  Prints the policy comparison against the
-independent per-request baseline.
+A synthetic user-item recommendation graph (power-law degrees on both
+sides) takes a Poisson stream of user ego-network queries; the server
+coalesces concurrent requests into ONE shared minibatch plan per
+dispatch (the paper's concavity argument applied to inference), gathers
+features through the warm device cache, and scatters per-request
+predictions back out with latency accounting.  Prints the policy
+comparison against the independent per-request baseline.
 """
 import argparse
 

@@ -1,6 +1,5 @@
 from repro.data.recsys import RecsysDataset, make_recsys, recsys_graph
 from repro.data.synthetic import SyntheticGraphDataset, rmat_graph
-from repro.data.tokens import synthetic_token_batch
 
 __all__ = [
     "RecsysDataset",
@@ -8,5 +7,4 @@ __all__ = [
     "make_recsys",
     "recsys_graph",
     "rmat_graph",
-    "synthetic_token_batch",
 ]

@@ -3,7 +3,7 @@
 This is the promotion of :class:`repro.core.cooperative.ShardExecutor`
 from a test-only wrapper to a first-class execution path.  A
 :class:`ShardRunner` binds a cooperative :class:`MinibatchEngine` to a
-real 1-D device mesh (:func:`repro.launch.mesh.make_coop_mesh`) and runs
+real 1-D device mesh (:func:`make_coop_mesh`) and runs
 the per-PE plan-construction and forward/backward bodies inside
 ``shard_map``, with ``jax.lax.all_to_all`` as the exchange primitive —
 the paper's Algorithm 1 on actual devices instead of a vmap simulation.
@@ -38,7 +38,7 @@ from typing import TYPE_CHECKING, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core.cooperative import (
     CoopMinibatch,
@@ -46,10 +46,36 @@ from repro.core.cooperative import (
     build_cooperative_minibatch,
 )
 from repro.core.graph import INVALID
-from repro.launch.mesh import make_coop_mesh
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard, typing only
     from repro.engine.engine import MinibatchEngine
+
+
+def make_coop_mesh(num_pes: int, axis_name: str = "data"):
+    """1-D mesh carrying the cooperative PE axis (one PE per device).
+
+    This is the mesh :class:`ShardRunner` runs ``shard_map`` over.  On
+    CPU, force a multi-device platform *before* importing jax::
+
+        XLA_FLAGS=--xla_force_host_platform_device_count=P
+
+    which is how CI exercises the real all-to-all path without TPUs.
+
+    The axis is ``Auto``: ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    under which plain indexing of a stacked ``(P, ...)`` output that is
+    sharded along the axis is a sharding type error.
+    """
+    avail = len(jax.devices())
+    if avail < num_pes:
+        raise ValueError(
+            f"cooperative shard execution needs num_pes={num_pes} devices, "
+            f"but jax sees {avail}; on CPU set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={num_pes} "
+            f"before importing jax"
+        )
+    return jax.make_mesh(
+        (num_pes,), (axis_name,), axis_types=(AxisType.Auto,)
+    )
 
 
 @dataclass
@@ -67,8 +93,9 @@ class ShardRunner:
         if cfg.mode != "cooperative":
             raise ValueError(
                 "ShardRunner needs a cooperative engine; independent mode "
-                "is plain data parallelism (no all-to-all) — shard it with "
-                "launch.shardings instead"
+                "is plain data parallelism (no all-to-all) and runs its P "
+                "PEs vmapped on one device with executor='sim'; there is no "
+                "multi-device independent path yet"
             )
         if not isinstance(engine.ex, ShardExecutor):
             raise ValueError(

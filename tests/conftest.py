@@ -1,5 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests run on ONE device;
-only the dry-run materializes the 512-device host platform."""
+multi-device tests start their own subprocess with forced host devices."""
 import jax
 import numpy as np
 import pytest
